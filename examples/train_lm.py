@@ -3,37 +3,25 @@
 
 The token stream arrives through a PipeGen data pipe (the paper's transport
 feeding the trainer — no file materialization between the "tokenizer" and
-the training loop).  Defaults to a reduced config that trains in seconds on
-CPU; ``--arch smollm-360m --full`` selects the real 360M config (sized for
-accelerators).
+the training loop); the loop is ``repro.launch.train``'s.  Defaults to a
+reduced config that trains in seconds on CPU; ``--arch smollm-360m --full``
+selects the real 360M config (sized for accelerators).
 
     PYTHONPATH=src python examples/train_lm.py --steps 60
     PYTHONPATH=src python examples/train_lm.py --steps 60 --resume  # restart
 """
 
 import argparse
+import os
 import sys
-import threading
+import tempfile
 
 sys.path.insert(0, "src")
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.core.datapipe import PipeConfig
-from repro.launch.mesh import make_local_mesh
-from repro.models import build_model, get_config
-from repro.pipeline import PipeFeeder, SyntheticSource
-from repro.train import (
-    CheckpointManager,
-    TrainState,
-    adamw_init,
-    make_train_step,
-)
+from repro.launch.train import main as train_main
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--steps", type=int, default=60)
@@ -42,65 +30,21 @@ def main() -> None:
     ap.add_argument("--full", action="store_true",
                     help="full-size config (accelerator-scale)")
     ap.add_argument("--resume", action="store_true")
-    ap.add_argument("--ckpt-dir", default="/tmp/pipegen-train-ckpt")
+    ap.add_argument("--ckpt-dir", default=os.path.join(
+        tempfile.gettempdir(), "pipegen-train-ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=20)
     args = ap.parse_args()
 
-    cfg = get_config(args.arch)
+    argv = ["--arch", args.arch, "--steps", str(args.steps),
+            "--batch", str(args.batch), "--seq", str(args.seq),
+            "--ckpt-dir", args.ckpt_dir,
+            "--ckpt-every", str(args.ckpt_every)]
     if not args.full:
-        cfg = cfg.reduced()
-    model = build_model(cfg)
-    mesh = make_local_mesh()
-    print(f"[train] arch={cfg.name} params~{cfg.param_count() if args.full else 'reduced'} "
-          f"mesh={dict(mesh.shape)}")
-
-    mgr = CheckpointManager(args.ckpt_dir)
-    params = model.init(jax.random.PRNGKey(0))
-    state = TrainState(params, adamw_init(params))
-    start_step = 0
+        argv.append("--reduced")
     if args.resume:
-        try:
-            restored, start_step = mgr.restore(jax.eval_shape(lambda: state))
-            state = jax.tree_util.tree_map(jnp.asarray, restored)
-            print(f"[train] resumed from checkpoint step {start_step}")
-        except FileNotFoundError:
-            print("[train] no checkpoint; cold start")
-
-    # the data plane: a synthetic "tokenizer" exports through a data pipe
-    n_rows = (args.steps - start_step) * args.batch + args.batch
-    pipe_name = "db://tokens?query=train"
-    feeder = PipeFeeder([pipe_name], batch_size=args.batch, seq_len=args.seq,
-                        skip_until=0).start()
-    src = SyntheticSource(cfg.vocab, args.seq, seed=7)
-    feed_thread = threading.Thread(
-        target=src.serve, args=(pipe_name, n_rows),
-        kwargs={"config": PipeConfig(mode="arrowcol", block_rows=256)},
-        daemon=True)
-    feed_thread.start()
-
-    step_mod = make_train_step(model, mesh, lr_peak=3e-3,
-                               lr_total=max(args.steps, 100))
-    jitted = jax.jit(step_mod.step_fn)
-
-    step = start_step
-    with mesh:
-        for batch in feeder.batches():
-            if step >= args.steps:
-                break
-            jb = {k: jnp.asarray(v) for k, v in batch.data.items()}
-            state, metrics = jitted(state, jb)
-            step += 1
-            if step % 10 == 0 or step == args.steps:
-                print(f"[train] step {step:4d} loss={float(metrics['loss']):.4f} "
-                      f"gnorm={float(metrics['grad_norm']):.3f} "
-                      f"lr={float(metrics['lr']):.2e} "
-                      f"queue_stalls={feeder.queue.stalls}")
-            if step % args.ckpt_every == 0:
-                mgr.save(step, state, blocking=False)
-    mgr.wait()
-    mgr.save(step, state)
-    print(f"[train] done at step {step}; checkpoint in {args.ckpt_dir}")
+        argv.append("--resume")
+    return train_main(argv)
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -12,7 +12,7 @@ __all__ = ["decode_attn"]
 
 def decode_attn(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                 length, *, use_pallas: bool = True,
-                interpret: bool = True, blk_s: int = 512) -> jnp.ndarray:
+                interpret: bool = False, blk_s: int = 512) -> jnp.ndarray:
     if use_pallas:
         return decode_attention(q, k_cache, v_cache, length,
                                 blk_s=blk_s, interpret=interpret)

@@ -12,7 +12,7 @@ __all__ = ["attention"]
 
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
               causal: bool = True, use_pallas: bool = True,
-              interpret: bool = True, blk_q: int = 128,
+              interpret: bool = False, blk_q: int = 128,
               blk_k: int = 128) -> jnp.ndarray:
     """Drop-in blockwise GQA attention; falls back to the jnp oracle."""
     if use_pallas:

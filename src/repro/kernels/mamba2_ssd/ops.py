@@ -9,7 +9,7 @@ __all__ = ["ssd"]
 
 
 def ssd(x, dt, A, B, C, D, state, *, use_pallas: bool = True,
-        interpret: bool = True, chunk: int = 64):
+        interpret: bool = False, chunk: int = 64):
     if use_pallas:
         return ssd_chunked(x, dt, A, B, C, D, state, chunk=chunk,
                            interpret=interpret)

@@ -14,7 +14,7 @@ __all__ = ["pivot", "pivot_columns"]
 
 
 def pivot(rows: jnp.ndarray, *, use_pallas: bool = True,
-          interpret: bool = True) -> jnp.ndarray:
+          interpret: bool = False) -> jnp.ndarray:
     """[N, W] row-major words -> [W, N] column-major words."""
     if use_pallas:
         return pivot_tiled(rows, interpret=interpret)
@@ -23,7 +23,7 @@ def pivot(rows: jnp.ndarray, *, use_pallas: bool = True,
 
 def pivot_columns(rows: jnp.ndarray, widths: Sequence[int], *,
                   use_pallas: bool = True,
-                  interpret: bool = True) -> List[jnp.ndarray]:
+                  interpret: bool = False) -> List[jnp.ndarray]:
     """[N, W] + per-column word widths -> list of [N, w_i] column tensors
     (each contiguous; i.e. the arrowcol layout on device)."""
     colmajor = pivot(rows, use_pallas=use_pallas, interpret=interpret)

@@ -9,7 +9,10 @@ traffic is one pass over the inputs — the memory-bound floor — versus a
 naive lax.scan which round-trips the state every step.
 
 Grid: (B*H, S/CHUNK).  hd is 64 for rwkv6 heads: the state tile is
-64x64xf32 = 16 KiB, so state + 4 input chunks fit VMEM comfortably.
+64x64xf32 = 16 KiB, so state + 4 input chunks fit VMEM comfortably.  The
+per-head bonus ``u`` is read as a [1, 1, hd] block of the [H, 1, hd] array
+(head = program b mod H), so its trailing dims are full-extent and it is
+never broadcast to B*H in HBM.
 """
 
 from __future__ import annotations
@@ -34,18 +37,20 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     def _init():
         state_ref[...] = s0_ref[0]
 
-    u = u_ref[0]                                   # [hd]
+    # rows are loaded as [1, hd] and turned into [hd, 1] columns by a 2-D
+    # transpose: the key axis of the state runs down the sublanes
+    u = u_ref[0].astype(jnp.float32).T             # [hd, 1]
 
     def step(t, _):
-        rt = r_ref[0, t].astype(jnp.float32)       # [hd]
-        kt = k_ref[0, t].astype(jnp.float32)
-        vt = v_ref[0, t].astype(jnp.float32)
-        wt = w_ref[0, t].astype(jnp.float32)
+        rt = r_ref[0, pl.ds(t, 1), :].astype(jnp.float32)   # [1, hd]
+        kt = k_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        vt = v_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        wt = w_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
         s = state_ref[...]                         # [hd, hd] key-major
-        kv = kt[:, None] * vt[None, :]             # outer product
-        y = jnp.einsum("k,kv->v", rt, s + u[:, None] * kv)
-        y_ref[0, t] = y.astype(y_ref.dtype)
-        state_ref[...] = wt[:, None] * s + kv
+        kv = kt.T * vt                             # outer product
+        y = jnp.sum(rt.T * (s + u * kv), axis=0, keepdims=True)  # [1, hd]
+        y_ref[0, pl.ds(t, 1), :] = y.astype(y_ref.dtype)
+        state_ref[...] = wt.T * s + kv
         return 0
 
     jax.lax.fori_loop(0, chunk, step, 0)
@@ -68,7 +73,7 @@ def wkv_chunked(r, k, v, w, u, state, chunk: int = CHUNK,
         return t.transpose(0, 2, 1, 3).reshape(BH, S, hd)
 
     rf, kf, vf, wf = flat(r), flat(k), flat(v), flat(w)
-    uf = jnp.broadcast_to(u[None], (B, H, hd)).reshape(BH, hd)
+    uf = u.reshape(H, 1, hd)
     sf = state.reshape(BH, hd, hd).astype(jnp.float32)
 
     grid = (BH, S // chunk)
@@ -81,7 +86,7 @@ def wkv_chunked(r, k, v, w, u, state, chunk: int = CHUNK,
             pl.BlockSpec((1, chunk, hd), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, hd), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, hd), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, hd), lambda b, c: (b, 0)),
+            pl.BlockSpec((1, 1, hd), lambda b, c: (b % H, 0, 0)),
             pl.BlockSpec((1, hd, hd), lambda b, c: (b, 0, 0)),
         ],
         out_specs=[

@@ -11,7 +11,7 @@ __all__ = ["wkv"]
 
 
 def wkv(r, k, v, w, u, state, *, use_pallas: bool = True,
-        interpret: bool = True, chunk: int = 64):
+        interpret: bool = False, chunk: int = 64):
     if use_pallas:
         return wkv_chunked(r, k, v, w, u, state, chunk=chunk,
                            interpret=interpret)

@@ -11,15 +11,6 @@ import jax
 __all__ = ["make_production_mesh", "make_local_mesh"]
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only where the installed jax has it (>= 0.5.x);
-    older versions default every axis to Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips).
 
@@ -29,11 +20,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh():
     """Whatever devices exist locally, as a (data, model) mesh with
     model=1 — used by smoke tests and the CPU examples."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"), **_axis_type_kwargs(2))
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -13,6 +13,7 @@ import jax
 
 from ..models import build_model, get_config
 from ..serve import ServeEngine
+from .compile_cache import use_compile_cache
 
 
 def main(argv=None) -> int:
@@ -26,6 +27,7 @@ def main(argv=None) -> int:
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

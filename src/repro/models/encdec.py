@@ -162,7 +162,7 @@ def init_cache(cfg: ModelConfig, batch: int, seq: int,
         "v": jnp.zeros((L, batch, seq, KV, hd), dt),
         "cross_k": jnp.zeros((L, batch, enc_len, KV, hd), dt),
         "cross_v": jnp.zeros((L, batch, enc_len, KV, hd), dt),
-        "index": jnp.zeros((), jnp.int32),
+        "index": jnp.zeros((batch,), jnp.int32),
     }
 
 
@@ -187,14 +187,11 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Dict[str, Any],
     x = embed(params["embedding"], batch["token"])
     index = cache["index"]
     d = cfg.d_model
-    # sinusoidal position of the current step
-    posvec = _sinusoid(1, d)[0]
-    ang_scale = jnp.ones(())  # static shape; recompute per index:
-    pos_t = jnp.where(jnp.arange(d // 2) >= 0, index.astype(jnp.float32), 0.0)
+    # sinusoidal position of each row's current step
     dim = jnp.arange(d // 2, dtype=jnp.float32)
-    ang = pos_t / jnp.power(10_000.0, 2 * dim / d)
-    pe = jnp.concatenate([jnp.sin(ang), jnp.cos(ang)])
-    x = x + pe.astype(x.dtype)
+    ang = index.astype(jnp.float32)[:, None] / jnp.power(10_000.0, 2 * dim / d)
+    pe = jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)   # [B, d]
+    x = x + pe[:, None, :].astype(x.dtype)
 
     def body(carry, inp):
         h = carry

@@ -223,24 +223,24 @@ def attention_decode(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                      index: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One-token decode over a KV cache.
 
-    x: [B, 1, d]; cache_k/v: [B, S, KV, hd]; index: [] current position.
+    x: [B, 1, d]; cache_k/v: [B, S, KV, hd]; index: [B] position of the new
+    token in each row, so every row is its own sequence.
     Returns (out [B,1,d], new_cache_k, new_cache_v).
     """
     B, S, KV, hd = cache_k.shape
     q, k, v = _project_qkv(params, x, x)
-    pos = jnp.full((B, 1), index, jnp.int32)
+    pos = index[:, None].astype(jnp.int32)               # [B, 1]
     if cfg.rope == "mrope":
         pos3 = jnp.broadcast_to(pos, (3,) + pos.shape)
         q, k = apply_mrope(q, pos3, cfg.rope_theta), apply_mrope(k, pos3, cfg.rope_theta)
     elif cfg.rope == "rope":
         q, k = apply_rope(q, pos, cfg.rope_theta), apply_rope(k, pos, cfg.rope_theta)
-    cache_k = jax.lax.dynamic_update_slice(cache_k, k.astype(cache_k.dtype),
-                                           (0, index, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(cache_v, v.astype(cache_v.dtype),
-                                           (0, index, 0, 0))
+    rows = jnp.arange(B)
+    cache_k = cache_k.at[rows, index].set(k[:, 0].astype(cache_k.dtype))
+    cache_v = cache_v.at[rows, index].set(v[:, 0].astype(cache_v.dtype))
     scores = _gqa_scores(q, cache_k)                     # [B,H,1,S]
-    valid = (jnp.arange(S) <= index)[None, None, None, :]
-    scores = jnp.where(valid, scores, -1e30)
+    valid = jnp.arange(S)[None, :] <= index[:, None]     # [B,S]
+    scores = jnp.where(valid[:, None, None, :], scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1)
     o = _gqa_out(w, cache_v)
     out = jnp.einsum("bshk,hkd->bsd", o.astype(x.dtype), params["wo"])

@@ -177,14 +177,17 @@ def n_shared_apps(cfg: ModelConfig) -> int:
 
 
 def init_cache(cfg: ModelConfig, batch: int, seq: int) -> Dict[str, Any]:
-    """Decode-time cache sized for a context of ``seq`` tokens."""
+    """Decode-time cache sized for a context of ``seq`` tokens.
+
+    ``index`` [batch] is each row's next position; every other leaf holds
+    the batch on axis 1.  An empty row is all zeros."""
     dt = dtype_of(cfg)
     L, KV, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
     if cfg.family == "ssm":
         xa, xf, wkv = rwkv6_init_state(cfg, batch)
         stack = lambda t: jnp.broadcast_to(t, (L,) + t.shape)
         return {"xp_att": stack(xa), "xp_ffn": stack(xf),
-                "wkv": stack(wkv), "index": jnp.zeros((), jnp.int32)}
+                "wkv": stack(wkv), "index": jnp.zeros((batch,), jnp.int32)}
     if cfg.family == "hybrid":
         conv, ssm = mamba2_init_state(cfg, batch)
         stack = lambda t: jnp.broadcast_to(t, (L,) + t.shape)
@@ -193,12 +196,12 @@ def init_cache(cfg: ModelConfig, batch: int, seq: int) -> Dict[str, Any]:
             "conv": stack(conv), "ssm": stack(ssm),
             "shared_k": jnp.zeros((apps, batch, seq, KV, hd), dt),
             "shared_v": jnp.zeros((apps, batch, seq, KV, hd), dt),
-            "index": jnp.zeros((), jnp.int32),
+            "index": jnp.zeros((batch,), jnp.int32),
         }
     return {
         "k": jnp.zeros((L, batch, seq, KV, hd), dt),
         "v": jnp.zeros((L, batch, seq, KV, hd), dt),
-        "index": jnp.zeros((), jnp.int32),
+        "index": jnp.zeros((batch,), jnp.int32),
     }
 
 

@@ -31,7 +31,6 @@ from typing import Any, Callable, Dict, Iterator, List, Optional
 import numpy as np
 
 from ..core.datapipe import DataPipeInput, DataPipeOutput, PipeConfig
-from ..core.types import ColType, ColumnBlock, Field, Schema
 
 __all__ = ["SyntheticSource", "EngineSource", "PipeFeeder", "BatchQueue"]
 
@@ -50,18 +49,21 @@ class SyntheticSource:
         self.seq_len = seq_len
         self.seed = seed
 
+    def rows(self, n_rows: int) -> Iterator[np.ndarray]:
+        """The ``n_rows`` token rows ``serve`` exports, regenerated from the
+        seed (what a consumer checks a delivered batch against)."""
+        rng = np.random.default_rng(self.seed)
+        for _ in range(n_rows):
+            yield rng.integers(0, self.vocab, self.seq_len)
+
     def serve(self, pipe_name: str, n_rows: int,
               config: Optional[PipeConfig] = None) -> None:
         """Export ``n_rows`` sequences through a data pipe (blocking)."""
-        rng = np.random.default_rng(self.seed)
         out = DataPipeOutput(pipe_name, config=config or PipeConfig())
-        schema = Schema([Field(f"t{i}", ColType.INT64)
-                         for i in range(self.seq_len)])
         # feed the pipe the way a decorated engine would: typed rows
         from ..core.astring import AString
 
-        for r in range(n_rows):
-            toks = rng.integers(0, self.vocab, self.seq_len)
+        for toks in self.rows(n_rows):
             parts: List[Any] = []
             for j, t in enumerate(toks):
                 if j:
@@ -126,6 +128,7 @@ class PipeFeeder:
         self.skip_until = skip_until
         self.rows_dropped = 0
         self.sources_abandoned = 0
+        self.errors: List[BaseException] = []  # why sources were abandoned
         self._row_q: "queue.Queue[Optional[np.ndarray]]" = queue.Queue(
             maxsize=batch_size * max(2, queue_depth) * 4)
         self._threads: List[threading.Thread] = []
@@ -147,8 +150,9 @@ class PipeFeeder:
                 for r in rows:
                     self._row_q.put(r.astype(np.int32))
             pipe.close()
-        except Exception:
+        except Exception as exc:  # a dead source must not stop the others
             self.sources_abandoned += 1
+            self.errors.append(exc)
         finally:
             self._row_q.put(None)  # source-finished marker
 

@@ -4,8 +4,11 @@ cache, with PipeGen pipes as the request/response transport option.
 Small but real: requests are queued, packed into the fixed batch, decoded
 step-by-step with the model's ``decode_step`` (greedy or temperature
 sampling), and finished sequences are swapped out for queued requests
-between steps (continuous batching).  On CPU this serves the reduced
-configs; the same code lowers for the production mesh.
+between steps (continuous batching).  Every cache row is its own sequence
+(per-row positions), and a new request starts from an emptied row, so a
+request's tokens do not depend on what else shares the batch.  Prompts are
+fed one token per step alongside the other rows' decoding.  On CPU this
+serves the reduced configs; the same code lowers for the production mesh.
 """
 
 from __future__ import annotations
@@ -110,9 +113,26 @@ class GenerationResult:
     latency_s: float = 0.0
 
 
+def _row_reset(model: Model, max_context: int):
+    """jit(cache, rows [B] bool) -> cache with those rows emptied."""
+    def reset(cache, rows):
+        empty = model.init_cache(rows.shape[0], max_context)
+
+        def pick(old, new):
+            shape = [1] * old.ndim
+            axis = 0 if old.ndim == 1 else 1   # ``index`` [B]; others [L,B,..]
+            shape[axis] = rows.shape[0]
+            return jnp.where(rows.reshape(shape), new, old)
+
+        return jax.tree_util.tree_map(pick, cache, empty)
+
+    return jax.jit(reset, donate_argnums=(0,))
+
+
 @dataclass
 class _Slot:
     request: Optional[GenerationResult] = None
+    pending: List[int] = field(default_factory=list)  # prompt not yet fed
     remaining: int = 0
     t0: float = 0.0
 
@@ -137,6 +157,7 @@ class ServeEngine:
         self.cache = model.init_cache(batch_size, max_context)
         self._tokens = np.zeros((batch_size, 1), np.int32)
         self._step = _shared_decode_step(model, mesh)
+        self._reset = _row_reset(model, max_context)
         self.steps_run = 0
         self.features: Optional[FeatureView] = None
 
@@ -152,6 +173,10 @@ class ServeEngine:
 
     # -- client API -------------------------------------------------------------
     def submit(self, prompt: List[int], max_new_tokens: int = 16) -> int:
+        if len(prompt) + max_new_tokens > self.max_context:
+            raise ValueError(
+                f"prompt of {len(prompt)} + {max_new_tokens} new tokens "
+                f"exceeds max_context={self.max_context}")
         rid = self._next_id
         self._next_id += 1
         req = GenerationResult(rid, list(prompt))
@@ -180,30 +205,35 @@ class ServeEngine:
 
     # -- internals -----------------------------------------------------------------
     def _fill_slots(self) -> None:
+        fresh = np.zeros(self.batch_size, bool)
         for i, slot in enumerate(self._slots):
             if slot.request is None and not self._queue.empty():
                 req = self._queue.get()
                 slot.request = req
+                slot.pending = list(req.prompt) or [self.eos]
                 slot.remaining = req._max_new  # type: ignore[attr-defined]
                 slot.t0 = time.perf_counter()
-                # prefill-by-decode: feed prompt tokens one by one (simple,
-                # exercises the cache path; production would batch-prefill)
-                self._prefill(i, req.prompt)
-
-    def _prefill(self, slot_idx: int, prompt: List[int]) -> None:
-        for t in prompt[:-1]:
-            self._tokens[slot_idx, 0] = t
-            # jnp.array, not asarray: on CPU asarray can alias the numpy
-            # buffer zero-copy, and we mutate _tokens again while the
-            # async dispatch may still be reading it (a real race --
-            # the source of the greedy-determinism flake)
-            batch = {"token": jnp.array(self._tokens)}
-            _, self.cache = self._step(self.params, self.cache, batch)
-        self._tokens[slot_idx, 0] = prompt[-1] if prompt else self.eos
+                fresh[i] = True
+        if fresh.any():
+            self.cache = self._reset(self.cache, jnp.asarray(fresh))
 
     def _decode_one_step(self, done: List[GenerationResult]) -> None:
-        batch = {"token": jnp.array(self._tokens)}
+        # a row samples once its last prompt token is fed; until then the
+        # step only fills its cache (prefill-by-decode)
+        sampling = np.zeros(self.batch_size, bool)
+        for i, slot in enumerate(self._slots):
+            if slot.request is None:
+                continue
+            if slot.pending:
+                self._tokens[i, 0] = slot.pending.pop(0)
+            sampling[i] = not slot.pending
+        # hand JAX a private copy: the transfer of a host buffer may still
+        # be reading it after dispatch returns, and _tokens is rewritten
+        # before the next step (jnp.array does not copy a numpy input first)
+        batch = {"token": jnp.asarray(self._tokens.copy())}
         logits, self.cache = self._step(self.params, self.cache, batch)
+        if not sampling.any():
+            return
         logits = np.asarray(logits[:, 0, :], np.float32)
         if self.temperature > 0:
             self._rng, sub = jax.random.split(self._rng)
@@ -212,7 +242,7 @@ class ServeEngine:
         else:
             nxt = np.argmax(logits, axis=-1)
         for i, slot in enumerate(self._slots):
-            if slot.request is None:
+            if not sampling[i]:
                 continue
             tok = int(nxt[i])
             slot.request.tokens.append(tok)

@@ -1,0 +1,69 @@
+"""Compile for a described TPU v5e, with no chip attached: the Pallas
+kernels at the widths of the registered configs that would call them, and
+the qwen2-1.5b decode step at full width (depth cut to 2 layers).  What the
+chip's compiler refuses fails here, at no chip time.
+
+The topology is described only inside the fixture: only one process at a
+time may load the TPU library, so no module may do it while it is imported.
+"""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.cases import WIDTHS, kernel_case
+from repro.models import build_model, get_config
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a described v5e:2x2, with the persistent compilation
+    cache off (an entry compiled for a described chip cannot be read back
+    without one)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.mark.parametrize("name", sorted(WIDTHS))
+def test_kernel_compiles_for_v5e(v5e_chip, name):
+    case = kernel_case(name)
+    compiled = jax.jit(case.kernel).lower(*_on(v5e_chip, case.args)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen2_decode_step_compiles_for_v5e(v5e_chip):
+    cfg = dataclasses.replace(get_config("qwen2-1.5b"), n_layers=2)
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(4, 1024))
+    token = jax.ShapeDtypeStruct((4, 1), jnp.int32)
+    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        _on(v5e_chip, params), _on(v5e_chip, cache),
+        {"token": _on(v5e_chip, token)}).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9

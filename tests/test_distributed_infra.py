@@ -91,8 +91,8 @@ model = build_model(cfg)
 ckpt = sys.argv[1]
 phase = sys.argv[2]
 mesh_shape = (4, 2) if phase == "save" else (2, 4)   # elastic re-mesh
-from repro.launch.mesh import _axis_type_kwargs
-mesh = jax.make_mesh(mesh_shape, ("data", "model"), **_axis_type_kwargs(2))
+mesh = jax.make_mesh(mesh_shape, ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 params = model.init(jax.random.PRNGKey(0))
 state = TrainState(params, adamw_init(params))
 specs = train_state_specs(state, mesh, cfg)

@@ -103,3 +103,33 @@ def test_serve_engine_greedy_deterministic(fresh_jax):
         return eng.run(max_steps=50)[0].tokens
 
     assert run_once() == run_once()
+
+
+def test_serve_engine_rows_are_independent():
+    """Continuous batching must not leak context between requests: each
+    answer equals the model's own greedy continuation of its prompt,
+    whatever shares the batch or used its row before."""
+    cfg = get_config("qwen2-1.5b").reduced()
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    prompts = [[5, 6, 7], [9], [1, 2, 3, 4, 5], [8, 8], [3, 1, 4, 1]]
+    eng = ServeEngine(model, params, batch_size=2, max_context=16,
+                      eos_token=-1)
+    rids = [eng.submit(p, max_new_tokens=4) for p in prompts]
+    by_id = {r.request_id: r for r in eng.run(max_steps=200)}
+    fwd = jax.jit(lambda p, t: model.forward(p, {"tokens": t}))
+    for rid, prompt in zip(rids, prompts):
+        seq = list(prompt)
+        for _ in range(4):
+            logits = fwd(params, jnp.asarray([seq], jnp.int32))
+            seq.append(int(jnp.argmax(logits[0, -1])))
+        assert by_id[rid].tokens == seq[len(prompt):], rid
+
+
+def test_serve_engine_rejects_overlong_request():
+    cfg = get_config("qwen2-1.5b").reduced()
+    model = build_model(cfg)
+    eng = ServeEngine(model, model.init(jax.random.PRNGKey(0)),
+                      batch_size=1, max_context=8)
+    with pytest.raises(ValueError):
+        eng.submit([1, 2, 3, 4], max_new_tokens=5)
