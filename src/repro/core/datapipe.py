@@ -34,7 +34,7 @@ import socket
 import threading
 import time
 from dataclasses import dataclass, field, replace
-from typing import Any, Iterator, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 from urllib.parse import parse_qs, urlparse
 
 from . import telemetry
@@ -299,10 +299,6 @@ class PipeStats:
 # TransferResult.  Bounded so an uncollected benchmark loop cannot grow it.
 
 _SINK_MAX = 256
-#: per-pipe cap on buffered phase spans (a traced pipe must stay O(1)
-#: in memory however long the stream runs; the whole-pipe span and the
-#: lifecycle spans always fit)
-_TSPAN_MAX = 4096
 _sink_lock = threading.Lock()
 # (dataset, query_id) -> {role: {attempt: PipeStats}}
 _stats_sink: "dict[Tuple[str, str], dict]" = {}
@@ -511,7 +507,25 @@ class _PipelinedSender:
         return max(0.0, busy - inter)
 
 
-class DataPipeOutput:
+class _PhaseSpans:
+    """A traced pipe end's phase spans: recorded as each phase ends, under
+    the trace ``_trace_id`` and the whole-pipe span ``_pipe_sid``."""
+
+    _trace_id: Optional[str]
+    _pipe_sid: str
+
+    def _record(self, name: str, t0: float,
+                attrs: Optional[Dict[str, Any]] = None,
+                t1: Optional[float] = None) -> None:
+        """Record a phase span, ending now unless ``t1`` is given."""
+        tr = telemetry.tracer()
+        if tr is not None:
+            tr.record(name, t0, time.monotonic() if t1 is None else t1,
+                      trace_id=self._trace_id, parent_id=self._pipe_sid,
+                      attrs=attrs)
+
+
+class DataPipeOutput(_PhaseSpans):
     """File-like write end of a data pipe (subtype-substitutable for the
     engines' text writers, per fig. 5)."""
 
@@ -530,19 +544,22 @@ class DataPipeOutput:
         self.stats = PipeStats()
         self.closed = False
         self._verify_rows: List[tuple] = []
-        # telemetry: spans are timed locally and recorded at close under
-        # the finally-resolved trace context (explicit config ctx beats
-        # the importer's registration ctx beats a fresh root), so both
-        # ends of the edge land in one trace no matter which side
-        # originated it.  The flight recorder notes lifecycle events for
-        # postmortem attachment (shared with the executor's edge recorder
-        # when the plan passes one in).
+        # telemetry: the trace context is final once the rendezvous is
+        # done (explicit config ctx beats the importer's registration ctx
+        # beats a fresh root), so both ends of the edge land in one trace
+        # no matter which side originated it, and every phase span is
+        # recorded as it ends.  The flight recorder notes lifecycle events
+        # for postmortem attachment (shared with the executor's edge
+        # recorder when the plan passes one in).
         if self.config.trace and not telemetry.tracing_enabled():
             telemetry.enable_tracing()
         self._trace_on = self.config.trace or telemetry.tracing_enabled()
         self._trace_ctx = self.config.trace_ctx or telemetry.current_ctx()
-        self._tspans: List[tuple] = []
         self._t_open = time.monotonic()
+        # the block being filled: when its first row was written, and the
+        # seconds spent parsing in write() since (traced pipes only)
+        self._fill_t0: Optional[float] = None
+        self._fill_write_s = 0.0
         self._recorder = self.config.recorder or FlightRecorder(
             self.config.flight_depth, name=f"export {rn.dataset}")
         self._recorder.note("export.open", dataset=rn.dataset,
@@ -576,14 +593,16 @@ class DataPipeOutput:
         else:
             self._transport = _connect(endpoint, self.config.link)
         if self._trace_on:
-            self._tspans.append(("export.rendezvous", _t_rdv,
-                                 time.monotonic(), None))
             if not self._trace_ctx:
                 self._trace_ctx = telemetry.new_trace_ctx()
+            self._trace_id, self._trace_parent = telemetry.split_ctx(
+                self._trace_ctx)
             # the span id the whole-pipe span will be recorded under at
-            # close; carried in the schema hello so importer spans parent
-            # to this exporter when the trace originates here
+            # close: the parent of every phase span, and carried in the
+            # schema hello so importer spans parent to this exporter when
+            # the trace originates here
             self._pipe_sid = telemetry.new_span_id()
+            self._record("export.rendezvous", _t_rdv)
         else:
             self._pipe_sid = ""
         self._recorder.note("export.connected")
@@ -647,11 +666,21 @@ class DataPipeOutput:
         if self.config.mode == "parts":
             self._write_parts(s)
             return _cheap_len(s)
+        if self._trace_on:
+            t0 = time.monotonic()
+            if self._fill_t0 is None:
+                self._fill_t0 = t0
+            self._parse(s)
+            self._fill_write_s += time.monotonic() - t0
+        else:
+            self._parse(s)
+        self._maybe_flush_rows()
+        return _cheap_len(s)
+
+    def _parse(self, s: Any) -> None:
         self._asm.write(s if isinstance(s, (AString, str)) else str(s))
         if isinstance(self._asm, JsonAssembler) and len(self._asm._parts) >= 1 << 16:
             self._asm.flush()  # retains any incomplete trailing document
-        self._maybe_flush_rows()
-        return _cheap_len(s)
 
     def writelines(self, lines: Sequence[Any]) -> None:
         for l in lines:
@@ -718,27 +747,21 @@ class DataPipeOutput:
             raise attach_flight(sender_err, self._recorder)
 
     def _emit_spans(self) -> None:
-        """Record the pipe's lifecycle spans under the resolved trace
-        context (buffered locally so late-arriving context — the
-        importer's registration — still wins over a fresh root)."""
+        """Record the whole-pipe span, the parent of the phase spans
+        already recorded under its pre-allocated id."""
         tr = telemetry.tracer()
         if not self._trace_on or tr is None:
             return
-        trace_id, parent = telemetry.split_ctx(
-            self._trace_ctx or telemetry.new_trace_ctx())
         rn = self.reserved
-        pipe_sid = tr.record(
+        tr.record(
             "export.pipe", self._t_open, time.monotonic(),
-            trace_id=trace_id, parent_id=parent,
-            span_id=self._pipe_sid or None,
+            trace_id=self._trace_id, parent_id=self._trace_parent,
+            span_id=self._pipe_sid,
             attrs={"dataset": rn.dataset, "query": rn.query_id,
                    "attempt": self.config.attempt, "mode": self.config.mode,
                    "bytes": self.stats.bytes_sent,
                    "frames": self.stats.frames_sent,
                    "rows": self.stats.rows})
-        for name, t0, t1, attrs in self._tspans:
-            tr.record(name, t0, t1, trace_id=trace_id,
-                      parent_id=pipe_sid, attrs=attrs)
 
     def __enter__(self) -> "DataPipeOutput":
         return self
@@ -753,10 +776,8 @@ class DataPipeOutput:
             try:
                 return self._send_impl(kind, segs, compress)
             finally:
-                if len(self._tspans) < _TSPAN_MAX:
-                    self._tspans.append((
-                        "export.send", t0, time.monotonic(),
-                        {"kind": kind.decode("ascii", "replace")}))
+                self._record("export.send", t0,
+                             {"kind": kind.decode("ascii", "replace")})
         return self._send_impl(kind, segs, compress)
 
     def _send_impl(self, kind: bytes, segs: SegmentList,
@@ -835,6 +856,8 @@ class DataPipeOutput:
             self._flush_rows()
 
     def _flush_rows(self, final: bool = False) -> None:
+        if self._trace_on:
+            t_flush = time.monotonic()
         if final:
             try:
                 self._asm.flush()
@@ -859,9 +882,22 @@ class DataPipeOutput:
             self._verify_rows.extend(rb.rows[:take])
             self._send_verify(RowBlock(rb.schema, rb.rows[:take]))
         segs = self._wire.encode_block(block, pool=self._pool)
+        if self._trace_on:
+            self._end_fill(t_flush, len(block))
         self._send(FRAME_BLOCK, segs)
         self.stats.rows += len(block)
         self.stats.blocks += 1
+
+    def _end_fill(self, t_flush: float, rows: int) -> None:
+        """The block filled since ``_fill_t0`` was flushed at ``t_flush``
+        and is now encoded: record both phases (the schema and verify
+        frames of a stream's first block are sent inside its encode)."""
+        if self._fill_t0 is not None:
+            self._record("export.fill", self._fill_t0,
+                         {"rows": rows, "write_s": self._fill_write_s},
+                         t1=t_flush)
+            self._fill_t0, self._fill_write_s = None, 0.0
+        self._record("export.encode", t_flush, {"rows": rows})
 
     # -- typed block fast path (decorated exporters, fig. 11 'full PipeGen') ------
     def accepts_blocks(self) -> bool:
@@ -935,7 +971,12 @@ class DataPipeOutput:
                 take = self.config.verify_first_n - len(self._verify_rows)
                 self._verify_rows.extend(rb.rows[:take])
                 self._send_verify(RowBlock(rb.schema, rb.rows[:take]))
-            segs = self._wire.encode_block(sub, pool=self._pool)
+            if self._trace_on:
+                t0 = time.monotonic()
+                segs = self._wire.encode_block(sub, pool=self._pool)
+                self._record("export.encode", t0, {"rows": len(sub)})
+            else:
+                segs = self._wire.encode_block(sub, pool=self._pool)
             self._send(FRAME_BLOCK, segs)
             self.stats.rows += len(sub)
             self.stats.blocks += 1
@@ -984,7 +1025,7 @@ class DataPipeOutput:
                    compress=False)
 
 
-class DataPipeInput:
+class DataPipeInput(_PhaseSpans):
     """File-like read end of a data pipe.
 
     Decorated importers use :meth:`blocks` (typed ColumnBlocks, zero text) or
@@ -1031,8 +1072,12 @@ class DataPipeInput:
             telemetry.enable_tracing()
         self._trace_on = trace or telemetry.tracing_enabled()
         self._trace_ctx = trace_ctx or telemetry.current_ctx()
-        self._tspans: List[tuple] = []
         self._t_open = time.monotonic()
+        # phase spans parent to the whole-pipe span, recorded at close
+        # under this id; the trace id is known once the schema hello has
+        # been read (``_resolve_trace``)
+        self._pipe_sid = telemetry.new_span_id() if self._trace_on else ""
+        self._trace_id: Optional[str] = None
         self._recorder = recorder or FlightRecorder(
             flight_depth, name=f"import {rn.dataset}")
         self._recorder.note("import.open", dataset=rn.dataset,
@@ -1115,9 +1160,7 @@ class DataPipeInput:
             conn, _ = lsock.accept()
             lsock.close()
             self._transport = SocketTransport(conn, link)
-        if self._trace_on:
-            self._tspans.append(("import.rendezvous", _t_rdv,
-                                 time.monotonic(), None))
+        self._t_rdv = (_t_rdv, time.monotonic())
         self._recorder.note("import.connected")
         if getattr(directory, "degraded", False):
             # the rendezvous went through the directory client's local
@@ -1369,13 +1412,13 @@ class DataPipeInput:
                     f"or abandoned the attempt"), self._recorder) from None
         else:
             kind, payload = self._transport.recv_frame()
-        if self._trace_on:
-            self._tspans.append(("import.wait_schema", t0,
-                                 time.monotonic(), None))
+        t1 = time.monotonic()
         if kind == FRAME_EOF:
             self._eof = True  # stub socket: orphaned importer (section 4.2)
             self._started = True
             self._recorder.note("import.orphaned_eof")
+            if self._trace_on:
+                self._resolve_trace((t0, t1))
             return
         if kind != FRAME_SCHEMA:
             raise IOError(f"pipe stream must begin with schema frame, got {kind!r}")
@@ -1385,6 +1428,8 @@ class DataPipeInput:
             # adopt the exporter's trace from the hello: our spans parent
             # under its pipe span, landing both ends in one trace
             self._trace_ctx = str(self.meta["trace"])
+        if self._trace_on:
+            self._resolve_trace((t0, t1))
         self._codec = get_codec(self.meta.get("codec", "none"))
         mode = self.meta.get("mode", "arrowcol")
         self._wire = (
@@ -1417,10 +1462,8 @@ class DataPipeInput:
             if self._trace_on:
                 t0 = time.monotonic()
                 kind, payload = self._transport.recv_frame()
-                if len(self._tspans) < _TSPAN_MAX:
-                    self._tspans.append((
-                        "import.wait", t0, time.monotonic(),
-                        {"kind": bytes(kind).decode("ascii", "replace")}))
+                self._record("import.wait", t0, {
+                    "kind": bytes(kind).decode("ascii", "replace")})
             else:
                 kind, payload = self._transport.recv_frame()
             if kind == FRAME_EOF:
@@ -1474,9 +1517,8 @@ class DataPipeInput:
                     data.decode("utf-8", "surrogatepass"))
             raise IOError(f"unexpected frame kind {kind!r}")  # pragma: no cover
         finally:
-            if self._trace_on and len(self._tspans) < _TSPAN_MAX:
-                self._tspans.append(("import.decode", t0,
-                                     time.monotonic(), None))
+            if self._trace_on:
+                self._record("import.decode", t0)
 
     # -- typed fast path -----------------------------------------------------------
     def blocks(self) -> Iterator[ColumnBlock]:
@@ -1757,26 +1799,35 @@ class DataPipeInput:
         self._emit_spans()
         self._transport.close()
 
+    def _resolve_trace(
+            self, wait_schema: Optional[Tuple[float, float]] = None) -> None:
+        """Fix the trace context (hello > own > registration) and record
+        the phases timed before it was known."""
+        self._trace_id, self._trace_parent = telemetry.split_ctx(
+            self._trace_ctx or self._reg_ctx)
+        self._record("import.rendezvous", self._t_rdv[0], t1=self._t_rdv[1])
+        if wait_schema is not None:
+            self._record("import.wait_schema", wait_schema[0],
+                         t1=wait_schema[1])
+
     def _emit_spans(self) -> None:
-        """Record the import-side lifecycle spans under the resolved
-        trace context (hello > registration > fresh root)."""
+        """Record the whole-pipe span, the parent of the phase spans
+        already recorded under its pre-allocated id."""
         tr = telemetry.tracer()
         if not self._trace_on or tr is None:
             return
-        ctx = self._trace_ctx or self._reg_ctx or telemetry.new_trace_ctx()
-        trace_id, parent = telemetry.split_ctx(ctx)
+        if self._trace_id is None:
+            self._resolve_trace()  # closed before the schema hello
         rn = self.reserved
-        pipe_sid = tr.record(
+        tr.record(
             "import.pipe", self._t_open, time.monotonic(),
-            trace_id=trace_id, parent_id=parent,
+            trace_id=self._trace_id, parent_id=self._trace_parent,
+            span_id=self._pipe_sid,
             attrs={"dataset": rn.dataset, "query": rn.query_id,
                    "attempt": self._attempt,
                    "mode": self.meta.get("mode"),
                    "rows": self.stats.rows,
                    "replayed": self.stats.resume_replayed})
-        for name, t0, t1, attrs in self._tspans:
-            tr.record(name, t0, t1, trace_id=trace_id,
-                      parent_id=pipe_sid, attrs=attrs)
 
     def __enter__(self) -> "DataPipeInput":
         return self

@@ -30,6 +30,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional
 
 import numpy as np
 
+from ..core import telemetry
 from ..core.datapipe import DataPipeInput, DataPipeOutput, PipeConfig
 
 __all__ = ["SyntheticSource", "EngineSource", "PipeFeeder", "BatchQueue"]
@@ -91,20 +92,25 @@ class EngineSource:
 
 
 class BatchQueue:
-    """Bounded prefetch queue (double buffering + backpressure)."""
+    """Bounded prefetch queue (double buffering + backpressure).
+
+    Every get's wait is observed in the registry histogram
+    ``feeder.get_wait_s``, traced or not: the consumer's input wait."""
 
     def __init__(self, depth: int = 2):
         self._q: "queue.Queue[Optional[Batch]]" = queue.Queue(maxsize=depth)
-        self.stalls = 0
+        self._get_wait = telemetry.histogram("feeder.get_wait_s")
 
     def put(self, b: Optional[Batch]) -> None:
         self._q.put(b)
 
     def get(self, timeout: float = 60.0) -> Optional[Batch]:
-        t0 = time.perf_counter()
-        b = self._q.get(timeout=timeout)
-        if time.perf_counter() - t0 > 0.05:
-            self.stalls += 1
+        with telemetry.span("feeder.get") as sp:
+            t0 = time.perf_counter()
+            b = self._q.get(timeout=timeout)
+            self._get_wait.observe(time.perf_counter() - t0)
+            if b is not None:
+                sp.set(batch=b.batch_id)
         return b
 
 
@@ -144,9 +150,12 @@ class PipeFeeder:
                     self.sources_abandoned += 1
                     break
                 last = now
-                rows = np.asarray(
-                    [np.asarray(c) for c in block.columns], dtype=np.int64
-                ).T  # [rows, seq]
+                # the put loop below waits on the consumer once the row
+                # queue is full, so only the pivot is this block's work
+                with telemetry.span("feeder.rows", rows=len(block)):
+                    rows = np.asarray(
+                        [np.asarray(c) for c in block.columns], dtype=np.int64
+                    ).T  # [rows, seq]
                 for r in rows:
                     self._row_q.put(r.astype(np.int32))
             pipe.close()
@@ -181,8 +190,9 @@ class PipeFeeder:
             rows.append(item[: self.seq_len])
             if len(rows) == self.batch_size:
                 if batch_id >= self.skip_until:
-                    tokens = np.stack(rows)
-                    labels = np.roll(tokens, -1, axis=1)
+                    with telemetry.span("feeder.batch", batch=batch_id):
+                        tokens = np.stack(rows)
+                        labels = np.roll(tokens, -1, axis=1)
                     self.queue.put(Batch(batch_id, {
                         "tokens": tokens, "labels": labels}))
                 batch_id += 1

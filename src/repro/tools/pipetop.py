@@ -3,7 +3,9 @@
 Polls the broker's directory server over its ``stats`` RPC (see
 :meth:`repro.core.broker.PipeBroker.stats`) and renders admission
 pressure, per-tenant/QoS grants and rejects, live resource use, pool
-occupancy and doorbell-hub activity as a plain-terminal dashboard::
+occupancy, doorbell-hub activity and, where a pipe feeder shares the
+broker's process, its consumer's input wait as a plain-terminal
+dashboard::
 
     python -m repro.tools.pipetop --host 127.0.0.1 --port 7070
 
@@ -145,6 +147,14 @@ def render(stats: Dict[str, Any], now: float = 0.0) -> str:
             f"doorbells   registered={stats.get('hub_registered', 0)} "
             f"wakeups={stats.get('hub_wakeups', 0)} "
             f"waits={stats.get('hub_waits', 0)}")
+    hists = (stats.get("metrics") or {}).get("histograms") or {}
+    wait = hists.get("feeder.get_wait_s")
+    if wait:
+        # a feeder in the broker's process: its consumer's input wait
+        lines.append(
+            f"input wait  n={wait.get('total', 0)} "
+            f"sum={_fmt_s(wait.get('sum'))} p50={_fmt_s(wait.get('p50'))} "
+            f"p95={_fmt_s(wait.get('p95'))}")
     pool = stats.get("pool") or {}
     bpool = stats.get("buffer_pool") or {}
     if pool or bpool:

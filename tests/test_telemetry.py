@@ -64,7 +64,11 @@ def test_disabled_span_is_shared_null_singleton():
 def test_disabled_pipes_record_nothing():
     """A full transfer with tracing off must leave the tracer untouched
     (the <2% fig11.telemetry_overhead rung measures the wall-clock side
-    of this; the structural side is asserted here)."""
+    of this; the structural side is asserted here), and so must a
+    feeder, whose ``feeder.get_wait_s`` histogram still counts every
+    get."""
+    from repro.pipeline import PipeFeeder, SyntheticSource
+
     block = make_paper_block(64, seed=2)
     name = "db://toff?query=1"
     got = {}
@@ -79,6 +83,19 @@ def test_disabled_pipes_record_nothing():
     _pump(name, block, PipeConfig(mode="arrowcol", block_rows=32))
     t.join(20)
     assert got["rows"] == 64
+
+    waits = telemetry.histogram("feeder.get_wait_s")
+    gets0 = waits.total
+    feed = "db://toffeed?query=1"
+    feeder = PipeFeeder([feed], batch_size=4, seq_len=8).start()
+    src = threading.Thread(target=SyntheticSource(50, 8, seed=1).serve,
+                           args=(feed, 12))
+    src.start()
+    batches = list(feeder.batches())
+    src.join(20)
+    assert not src.is_alive()
+    assert len(batches) == 3
+    assert waits.total - gets0 == 4  # three batches and the end of stream
     assert telemetry.tracer() is None  # nothing silently enabled it
 
 
@@ -86,9 +103,14 @@ def test_disabled_pipes_record_nothing():
 
 
 def _pump(name, block, config):
+    out = DataPipeOutput(name, config=config)
+    _write_rows(out, block)
+    out.close()
+
+
+def _write_rows(out, block):
     from repro.core.astring import AString
 
-    out = DataPipeOutput(name, config=config)
     for row in block.to_rows().rows:
         parts = []
         for j, v in enumerate(row):
@@ -97,7 +119,6 @@ def _pump(name, block, config):
             parts.append(v)
         parts.append("\n")
         out.write(AString(parts))
-    out.close()
 
 
 def test_nested_spans_share_trace_and_parent():
@@ -176,15 +197,87 @@ def test_traced_transfer_single_trace_in_process():
     spans = tr.spans()
     names = {s.name for s in spans}
     assert {"export.pipe", "import.pipe", "export.rendezvous",
-            "import.rendezvous", "export.send", "import.wait_schema",
-            "import.wait", "import.decode"} <= names
+            "import.rendezvous", "export.fill", "export.encode",
+            "export.send", "import.wait_schema", "import.wait",
+            "import.decode"} <= names
     assert len({s.trace_id for s in spans}) == 1  # ONE trace
     by_name = {s.name: s for s in spans}
     assert by_name["export.pipe"].attrs["rows"] == 64
-    # the importer's pipe span parents to the exporter's via the hello
-    # (or vice versa via the registration) — either way, linked
-    assert by_name["export.rendezvous"].parent_id == \
-        by_name["export.pipe"].span_id
+    # every phase span parents to its side's pipe span; the importer's
+    # pipe span parents to the exporter's via the hello
+    exp, imp = by_name["export.pipe"], by_name["import.pipe"]
+    for s in spans:
+        if s.name.startswith("export.") and s is not exp:
+            assert s.parent_id == exp.span_id, s.name
+        elif s.name.startswith("import.") and s is not imp:
+            assert s.parent_id == imp.span_id, s.name
+    assert imp.parent_id == exp.span_id
+
+
+def test_traced_export_records_phase_spans_before_close():
+    """Phase spans are recorded as each phase ends, not at close: a
+    block's fill (first row to flush, with the seconds spent parsing in
+    write), its encode and its send are in the tracer while the pipe is
+    still open."""
+    tr = telemetry.enable_tracing()
+    block = make_paper_block(64, seed=4)
+    name = "db://tstream?query=1"
+    got = {}
+
+    def imp():
+        pipe = DataPipeInput(name)
+        got["rows"] = sum(len(b) for b in pipe.blocks())
+        pipe.close()
+
+    t = threading.Thread(target=imp)
+    t.start()
+    out = DataPipeOutput(name, config=PipeConfig(mode="arrowcol",
+                                                 block_rows=32))
+    _write_rows(out, block)
+    open_spans = tr.spans()
+    out.close()
+    t.join(20)
+    assert got["rows"] == 64
+    by = {}
+    for s in open_spans:
+        by.setdefault(s.name, []).append(s)
+    assert "export.pipe" not in by  # the whole-pipe span comes at close
+    fills = by["export.fill"]
+    assert [f.attrs["rows"] for f in fills] == [32, 32]
+    for f in fills:
+        assert 0 < f.attrs["write_s"] <= f.duration
+    assert [e.attrs["rows"] for e in by["export.encode"]] == [32, 32]
+    # each block's encode starts where its fill ended
+    assert [e.t0 for e in by["export.encode"]] == [f.t1 for f in fills]
+    assert [s.attrs["kind"] for s in by["export.send"]].count("B") == 2
+    # the phase spans already name the pipe span recorded at close
+    pipe_sid = next(s.span_id for s in tr.spans() if s.name == "export.pipe")
+    assert {s.parent_id for s in open_spans
+            if s.name.startswith("export.")} == {pipe_sid}
+
+
+def test_traced_write_block_records_encode_per_frame():
+    tr = telemetry.enable_tracing()
+    block = make_paper_block(40, seed=5)
+    name = "db://tblock?query=1"
+    got = {}
+
+    def imp():
+        pipe = DataPipeInput(name)
+        got["rows"] = sum(len(b) for b in pipe.blocks())
+        pipe.close()
+
+    t = threading.Thread(target=imp)
+    t.start()
+    out = DataPipeOutput(name, config=PipeConfig(mode="arrowcol",
+                                                 block_rows=16))
+    out.write_block(block)
+    encodes = [s for s in tr.spans() if s.name == "export.encode"]
+    out.close()
+    t.join(20)
+    assert got["rows"] == 40
+    assert [e.attrs["rows"] for e in encodes] == [16, 16, 8]
+    assert not any(s.name == "export.fill" for s in tr.spans())
 
 
 # -- cross-process propagation -------------------------------------------------------
@@ -441,8 +534,11 @@ def test_pipetop_renders_canned_snapshot_without_broker():
         "hub_registered": 2, "hub_wakeups": 40, "hub_waits": 41,
         "pool": {"spsc_parked": 1, "broadcast_parked": 0},
         "buffer_pool": {"hits": 10, "misses": 2, "bytes_retained": 4096},
+        "metrics": {"histograms": {"feeder.get_wait_s": {
+            "total": 21, "sum": 5.75, "p50": 0.0001, "p95": 0.0016}}},
     })
     assert "queue_depth=4" in text
+    assert "input wait  n=21 sum=5.75s" in text
     assert "acme" in text and "latency=3" in text and "bulk=2" in text
     assert "registered=2" in text
     assert "hit/miss=10/2" in text
