@@ -2,13 +2,15 @@
 mode on CPU; tiled for VMEM/MXU on real hardware):
 
     pivot        FormOpt section 5.4 row->column pivot, on device
-    flashattn    blockwise causal GQA attention (train / prefill)
+    flashattn    flash causal GQA attention with its backward (train /
+                 prefill; JAX's splash attention kernels)
     decode_attn  one-token attention over a long KV cache (serving)
     rwkv6_scan   RWKV-6 WKV recurrence, chunk-tiled
     mamba2_ssd   Mamba-2 SSD chunk-parallel dual form
 
 Each package: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit wrapper
-with a use_pallas/ref switch), ref.py (pure-jnp oracle).
+with a use_pallas/ref switch), ref.py (pure-jnp oracle); flashattn's ops.py
+wraps JAX's splash attention and has no kernel.py.
 """
 
 from .pivot.ops import pivot, pivot_columns

@@ -25,6 +25,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..core import telemetry
+from ..kernels.flashattn.ops import attention as flash_attention
+from ..kernels.flashattn.ops import supports as flash_supports
 from .config import ModelConfig
 
 Params = Dict[str, Any]
@@ -191,31 +194,83 @@ def _constrain_seq(t: jnp.ndarray, mesh, seq_dim: int) -> jnp.ndarray:
         t, jax.sharding.NamedSharding(mesh, P(*dims)))
 
 
+def _on_tpu(mesh) -> bool:
+    """Whether the arrays of a call under ``mesh`` (or, without one, of the
+    default backend) live on TPU."""
+    if mesh is None:
+        return jax.default_backend() == "tpu"
+    return mesh.devices.flat[0].platform == "tpu"
+
+
+def _batch_axes(mesh) -> Tuple[str, ...]:
+    return tuple(a for a in mesh.axis_names if a != "model")
+
+
+def _takes_flash(q: jnp.ndarray, k: jnp.ndarray, causal: bool,
+                 self_attn: bool, mesh) -> bool:
+    """Causal self-attention on TPU that the flash kernel takes, with heads
+    and sequence whole on every device (no `model` axis above 1) and the
+    batch split evenly over the other axes."""
+    if not (causal and self_attn and flash_supports(q.shape, k.shape)
+            and _on_tpu(mesh)):
+        return False
+    if mesh is None:
+        return True
+    split = math.prod(mesh.shape[a] for a in _batch_axes(mesh))
+    return mesh.shape.get("model", 1) == 1 and q.shape[0] % split == 0
+
+
+def _flash(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, mesh) -> jnp.ndarray:
+    """The flash kernel, under shard_map over the batch axes where a mesh
+    is given, so that each device runs it on its own rows."""
+    if mesh is None:
+        return flash_attention(q, k, v)
+    spec = P(_batch_axes(mesh) or None, None, None, None)
+    return jax.shard_map(flash_attention, mesh=mesh,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)(q, k, v)
+
+
 def attention(params: Params, cfg: ModelConfig, x: jnp.ndarray,
               pos: jnp.ndarray, *, causal: bool = True,
               x_kv: Optional[jnp.ndarray] = None, mesh=None) -> jnp.ndarray:
     """Full-sequence attention (train / prefill). pos: [B,S] or [3,B,S].
 
-    With a mesh, the query sequence dim is sharded over `model` (context
-    parallelism): score/softmax compute and memory scale 1/|model| for any
-    head count; K/V stay gathered (they are KV-head sized, GQA-small)."""
+    Causal self-attention on TPU runs the flash kernel (``_takes_flash``),
+    which never writes the [Sq, Sk] score plane.  Every other call
+    computes the plane with einsums; with a mesh, the query sequence dim is
+    then sharded over `model` (context parallelism): score/softmax compute
+    and memory scale 1/|model| for any head count; K/V stay gathered (they
+    are KV-head sized, GQA-small).  ``model.attention_path`` counts the
+    path each call site takes, once per trace."""
     xkv = x if x_kv is None else x_kv
     q, k, v = _project_qkv(params, x, xkv)
     if cfg.rope == "mrope":
         q, k = apply_mrope(q, pos, cfg.rope_theta), apply_mrope(k, pos, cfg.rope_theta)
     elif cfg.rope == "rope":
         q, k = apply_rope(q, pos, cfg.rope_theta), apply_rope(k, pos, cfg.rope_theta)
+    flash = _takes_flash(q, k, causal, x_kv is None, mesh)
+    telemetry.counter("model.attention_path",
+                      path="kernel" if flash else "einsum").inc()
+    if flash:
+        o = _flash(q, k, v, mesh)
+    else:
+        o = _attend_einsum(q, k, v, causal and x_kv is None, mesh)
+    return jnp.einsum("bshk,hkd->bsd", o.astype(x.dtype), params["wo"])
+
+
+def _attend_einsum(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                   causal: bool, mesh) -> jnp.ndarray:
     q = _constrain_seq(q, mesh, 1)
     scores = _gqa_scores(q, k)
     scores = _constrain_seq(scores, mesh, 2)
-    if causal and x_kv is None:
+    if causal:
         Sq, Sk = scores.shape[-2], scores.shape[-1]
         mask = jnp.tril(jnp.ones((Sq, Sk), bool), Sk - Sq)
         scores = jnp.where(mask, scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1)
     o = _gqa_out(w, v)
-    o = _constrain_seq(o, mesh, 1)
-    return jnp.einsum("bshk,hkd->bsd", o.astype(x.dtype), params["wo"])
+    return _constrain_seq(o, mesh, 1)
 
 
 def attention_decode(params: Params, cfg: ModelConfig, x: jnp.ndarray,

@@ -16,6 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.cases import WIDTHS, kernel_case
+from repro.kernels.flashattn.ops import attention
 from repro.models import build_model, get_config
 
 
@@ -54,6 +55,20 @@ def test_kernel_compiles_for_v5e(v5e_chip, name):
     case = kernel_case(name)
     compiled = jax.jit(case.kernel).lower(*_on(v5e_chip, case.args)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_backward_compiles_for_v5e(v5e_chip):
+    """The train step's attention at smollm-360m's train shape (8 x 2048,
+    15/5 heads of 64): forward with residuals, dq and dkv kernels."""
+    q = jax.ShapeDtypeStruct((8, 2048, 15, 64), jnp.bfloat16,
+                             sharding=v5e_chip)
+    kv = jax.ShapeDtypeStruct((8, 2048, 5, 64), jnp.bfloat16,
+                              sharding=v5e_chip)
+    grad = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        attention(q, k, v).astype(jnp.float32)), (0, 1, 2)))
+    text = grad.lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "2048,2048" not in text
 
 
 def test_qwen2_decode_step_compiles_for_v5e(v5e_chip):

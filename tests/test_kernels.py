@@ -12,7 +12,7 @@ except ImportError:
 
 from repro.kernels.decode_attn.ops import decode_attn
 from repro.kernels.decode_attn.ref import decode_attention_ref
-from repro.kernels.flashattn.ops import attention
+from repro.kernels.flashattn.ops import attention, block_for
 from repro.kernels.flashattn.ref import attention_ref
 from repro.kernels.mamba2_ssd.ops import ssd
 from repro.kernels.mamba2_ssd.ref import ssd_ref
@@ -76,12 +76,15 @@ def test_flashattn_sweep(B, Sq, Sk, H, KV, hd, causal, dtype):
 
 
 def test_flashattn_block_size_invariance():
+    """Blocks follow the sequence length (384 -> 128, 512 -> 512); causal
+    rows over a prefix do not depend on what follows it."""
+    assert (block_for(384), block_for(512)) == (128, 512)
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(ks[0], (1, 512, 4, 64))
     k = jax.random.normal(ks[1], (1, 512, 2, 64))
     v = jax.random.normal(ks[2], (1, 512, 2, 64))
-    a = attention(q, k, v, interpret=True, blk_q=128, blk_k=128)
-    b = attention(q, k, v, interpret=True, blk_q=256, blk_k=64)
+    a = attention(q, k, v, interpret=True)[:, :384]
+    b = attention(q[:, :384], k[:, :384], v[:, :384], interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=2e-5, atol=2e-5)
 
