@@ -1,12 +1,10 @@
 """Small copies of the benchmark for the CPU tests.
 
 ``small_copy`` copies ``BENCHMARK.json`` and ``benchmarks/chip`` into a
-directory, adds the cells of ``staged.json`` (built and run, but not yet
-proven on the chip, so not in ``BENCHMARK.json``), and cuts every
-configuration and mix to a size the CPU runs in seconds; ``cpu_trace``
-gives a CPU profile the device lines it lacks (the harness's dispatch and
-decode spans stand in for device ops), so that the traced path runs end to
-end without a chip.
+directory and cuts every configuration and mix to a size the CPU runs in
+seconds; ``cpu_trace`` gives a CPU profile the device lines it lacks (the
+harness's dispatch and decode spans stand in for device ops), so that the
+traced path runs end to end without a chip.
 """
 
 from __future__ import annotations
@@ -32,31 +30,12 @@ def _edit(path: Path, **changes) -> None:
     path.write_text(json.dumps(data, indent=1))
 
 
-def with_staged() -> dict:
-    """``BENCHMARK.json`` with the cells of ``staged.json`` merged in."""
-    bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    path = REPO / "benchmarks" / "chip" / "staged.json"
-    staged = json.loads(path.read_text()) if path.is_file() else {}
-    have = {c["name"] for c in bench["configs"]}
-    bench["configs"] += [c for c in staged.get("configs", [])
-                         if c["name"] not in have]
-    bench["workloads"] += staged.get("workloads", [])
-    for group in ("end_to_end", "per_layer"):
-        by_name = {m["name"]: m for m in bench[group]}
-        for m in staged.get(group, []):
-            if m["name"] in by_name:
-                by_name[m["name"]]["workloads"] += m["workloads"]
-            else:
-                bench[group].append(m)
-    return bench
-
-
 def small_copy(dst: Path) -> Path:
     dst = Path(dst)
     bench = dst / "benchmarks" / "chip"
     shutil.copytree(REPO / "benchmarks" / "chip", bench,
                     ignore=shutil.ignore_patterns("__pycache__", "test_*"))
-    (dst / "BENCHMARK.json").write_text(json.dumps(with_staged(), indent=1))
+    shutil.copy(REPO / "BENCHMARK.json", dst / "BENCHMARK.json")
     for conf in (bench / "configs").glob("*.json"):
         _edit(conf, **TINY_MODEL)
     for mix in (bench / "traffic").glob("*.json"):

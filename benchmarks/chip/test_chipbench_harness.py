@@ -1,6 +1,7 @@
-"""The chip benchmark's harness on the CPU: no chip, no result; each cell's
-driver at a small size prints the result line the contract names; a cell
-made of new files and a new entry is found by name."""
+"""The chip benchmark's harness on the CPU: every cell of ``BENCHMARK.json``
+names files that exist and metrics it reports; no chip, no result; each
+cell's driver at a small size prints the result line the contract names; a
+cell made of new files and a new entry is found by name."""
 
 import json
 import os
@@ -15,16 +16,38 @@ sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(HERE.parents[1] / "src"))
 
 import run  # noqa: E402
-from chipbench import testkit  # noqa: E402
+from chipbench import spec, testkit  # noqa: E402
 
 REPO = HERE.parents[1]
-CELLS = [w["name"] for w in testkit.with_staged()["workloads"]]
+BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
+CELLS = [w["name"] for w in BENCH["workloads"]]
 KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 
 
+def test_metric_workloads_are_cells():
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert set(m.get("workloads", [])) <= set(CELLS), m["name"]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_files_exist_and_it_reports_metrics(cell):
+    w = next(w for w in BENCH["workloads"] if w["name"] == cell)
+    conf = next(c for c in BENCH["configs"] if c["name"] == w["config"])
+    bench_dir = REPO / spec.BENCH_DIR
+    assert (REPO / conf["file"]).is_file()
+    assert (bench_dir / "traffic" / f"{w['traffic']}.json").is_file()
+    assert (bench_dir / "limits" / f"{cell}.json").is_file()
+    c = spec.load_cell(REPO, cell)
+    assert {m["name"] for m in c.end_to_end} > {"setup_s"}
+    assert c.per_layer
+    for kind, name in ([("drivers", c.traffic["driver"]),
+                        ("references", c.config["reference"])]
+                       + [("metrics", m["name"]) for m in c.per_layer]):
+        assert (bench_dir / kind / f"{name}.py").is_file(), (kind, name)
+
+
 def _command(root, cwd, env):
-    bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    cmd = bench["command"] + ["--workload", CELLS[0], "--seed", "7",
+    cmd = BENCH["command"] + ["--workload", CELLS[0], "--seed", "7",
                               "--seconds", "1", "--trace", "0"]
     return subprocess.run(cmd, cwd=cwd, env=env, capture_output=True,
                           text=True, timeout=120)
