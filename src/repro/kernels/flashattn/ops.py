@@ -5,9 +5,10 @@ the blocks a causal mask hides and never write the [Sq, Sk] score plane.
 K and V keep their KV heads: each q head reads the K/V head of its group.
 q and k enter the MXU as the values they are, with float32 accumulation;
 the softmax's running max and sum and the output accumulator are float32.
-The 1/sqrt(hd) scale is applied to q before the kernel, in float32 and
-cast back to q's dtype: exact where hd is a power of four (64 or 256),
-one rounding of q's dtype otherwise.
+The softmax scale (``scale``, 1/sqrt(hd) by default) is applied to q
+before the kernel, in float32 and cast back to q's dtype: exact where it is
+a power of two (1/sqrt(hd) for hd 64 or 256), one rounding of q's dtype
+otherwise.
 
 The blocks are a function of the sequence length: ``block_for``.
 """
@@ -67,16 +68,20 @@ def _kernel(heads: int, sq: int, sk: int, causal: bool, interpret: bool):
 
 
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-              causal: bool = True, interpret: bool = False) -> jnp.ndarray:
+              causal: bool = True, scale: Optional[float] = None,
+              interpret: bool = False) -> jnp.ndarray:
     """q: [B,Sq,H,hd]; k, v: [B,Sk,KV,hd], KV dividing H; causal masks
-    aligned at the end (query i sees keys up to i + Sk - Sq).  Returns
-    [B,Sq,H,hd] in q's dtype.  Differentiable in q, k and v."""
+    aligned at the end (query i sees keys up to i + Sk - Sq); the scores
+    are scaled by ``scale`` (None: 1/sqrt(hd)).  Returns [B,Sq,H,hd] in q's
+    dtype.  Differentiable in q, k and v."""
     if not supports(q.shape, k.shape):
         raise ValueError(f"flash attention does not take q {q.shape}, "
                          f"k {k.shape}")
     _, sq, h, hd = q.shape
     kernel = _kernel(h, sq, k.shape[1], causal, interpret)
-    q = (q.astype(jnp.float32) / math.sqrt(hd)).astype(q.dtype)
+    qf = q.astype(jnp.float32)
+    qf = qf / math.sqrt(hd) if scale is None else qf * scale
+    q = qf.astype(q.dtype)
     heads_first = lambda t: t.transpose(0, 2, 1, 3)
     out = jax.vmap(kernel)(heads_first(q), heads_first(k), heads_first(v))
     return heads_first(out)

@@ -32,6 +32,12 @@ class ModelConfig:
     qkv_bias: bool = False
     rope: str = "rope"                   # rope | mrope | none
     rope_theta: float = 10_000.0
+    attn_scale: Optional[float] = None   # softmax scale; None: 1/sqrt(hd)
+    # -- stream multipliers (Granite): x = embed * m_e; x += m_r * branch;
+    #    logits = unembed / logits_scaling.  1.0 leaves the program as is --
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # -- SSM / linear-attention ---------------------------------------------------
     ssm_state: int = 0                   # mamba2 state size (hybrid)
     ssm_head_dim: int = 64
@@ -39,6 +45,9 @@ class ModelConfig:
     expand: int = 2                      # mamba2 inner expansion
     # -- hybrid (zamba2): one shared attention block applied every k layers ------
     shared_attn_every: int = 0
+    # -- hybrid by layer list (Granite 4.0-H): the mixer of each layer,
+    #    "mamba" or "attention"; every layer has its own SwiGLU MLP ----------
+    layer_types: Tuple[str, ...] = ()
     # -- encoder-decoder (whisper) -----------------------------------------------
     encoder_layers: int = 0
     # -- numerics -----------------------------------------------------------------
@@ -50,6 +59,14 @@ class ModelConfig:
     # -- bookkeeping ----------------------------------------------------------------
     source: str = ""
     notes: str = ""
+
+    def __post_init__(self):
+        if self.layer_types and len(self.layer_types) != self.n_layers:
+            raise ValueError(f"{self.name}: {len(self.layer_types)} layer "
+                             f"types for {self.n_layers} layers")
+        if set(self.layer_types) - {"mamba", "attention"}:
+            raise ValueError(f"{self.name}: unknown layer types "
+                             f"{sorted(set(self.layer_types))}")
 
     @property
     def hd(self) -> int:
@@ -120,6 +137,8 @@ class ModelConfig:
             ssm_head_dim=16,
             rwkv_head_dim=16,
             shared_attn_every=2 if self.shared_attn_every else 0,
+            # one layer of each kind, in the order the list first has them
+            layer_types=tuple(dict.fromkeys(self.layer_types)),
             encoder_layers=2 if self.encoder_layers else 0,
             dtype="float32",
         )
@@ -206,6 +225,17 @@ _register(ModelConfig(
     d_ff=5120, vocab=51_866, rope="none", encoder_layers=32,
     source="arXiv:2212.04356 (unverified)",
     notes="enc-dec; conv frontend stubbed (precomputed frame embeddings)",
+))
+_register(ModelConfig(
+    name="granite-4.0-h-micro", family="hybrid",
+    n_layers=40, d_model=2048, n_heads=32, n_kv_heads=8, head_dim=64,
+    d_ff=8192, vocab=100_352, rope="none", attn_scale=1 / 64,
+    ssm_state=128, ssm_head_dim=64, expand=2,
+    layer_types=(("mamba",) * 5 + ("attention",) + ("mamba",) * 4) * 4,
+    embedding_multiplier=12.0, residual_multiplier=0.22, logits_scaling=8.0,
+    source="https://huggingface.co/ibm-granite/granite-4.0-h-micro/blob/main/config.json",
+    notes="GraniteMoeHybrid without experts: 36 Mamba-2 and 4 NoPE GQA "
+          "mixers, each layer with its own SwiGLU; tied head",
 ))
 _register(ModelConfig(
     name="zamba2-7b", family="hybrid",

@@ -158,15 +158,18 @@ def _project_qkv(params: Params, xq: jnp.ndarray, xkv: jnp.ndarray):
     return q, k, v
 
 
-def _gqa_scores(q: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
-    """q: [B,Sq,H,hd], k: [B,Sk,KV,hd] -> scores [B,H,Sq,Sk] (f32)."""
+def _gqa_scores(q: jnp.ndarray, k: jnp.ndarray,
+                scale: Optional[float] = None) -> jnp.ndarray:
+    """q: [B,Sq,H,hd], k: [B,Sk,KV,hd] -> scores [B,H,Sq,Sk] (f32), times
+    ``scale`` (None: over sqrt(hd))."""
     B, Sq, H, hd = q.shape
     KV = k.shape[2]
     g = H // KV
     qg = q.reshape(B, Sq, KV, g, hd)
     s = jnp.einsum("bqkgh,bskh->bkgqs", qg.astype(jnp.float32),
                    k.astype(jnp.float32))
-    return s.reshape(B, KV * g, Sq, k.shape[1]) / math.sqrt(hd)
+    s = s.reshape(B, KV * g, Sq, k.shape[1])
+    return s / math.sqrt(hd) if scale is None else s * scale
 
 
 def _gqa_out(w: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
@@ -220,13 +223,15 @@ def _takes_flash(q: jnp.ndarray, k: jnp.ndarray, causal: bool,
     return mesh.shape.get("model", 1) == 1 and q.shape[0] % split == 0
 
 
-def _flash(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, mesh) -> jnp.ndarray:
+def _flash(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, mesh,
+           scale: Optional[float]) -> jnp.ndarray:
     """The flash kernel, under shard_map over the batch axes where a mesh
     is given, so that each device runs it on its own rows."""
+    kernel = partial(flash_attention, scale=scale)
     if mesh is None:
-        return flash_attention(q, k, v)
+        return kernel(q, k, v)
     spec = P(_batch_axes(mesh) or None, None, None, None)
-    return jax.shard_map(flash_attention, mesh=mesh,
+    return jax.shard_map(kernel, mesh=mesh,
                          in_specs=(spec, spec, spec), out_specs=spec,
                          check_vma=False)(q, k, v)
 
@@ -253,16 +258,18 @@ def attention(params: Params, cfg: ModelConfig, x: jnp.ndarray,
     telemetry.counter("model.attention_path",
                       path="kernel" if flash else "einsum").inc()
     if flash:
-        o = _flash(q, k, v, mesh)
+        o = _flash(q, k, v, mesh, cfg.attn_scale)
     else:
-        o = _attend_einsum(q, k, v, causal and x_kv is None, mesh)
+        o = _attend_einsum(q, k, v, causal and x_kv is None, mesh,
+                           cfg.attn_scale)
     return jnp.einsum("bshk,hkd->bsd", o.astype(x.dtype), params["wo"])
 
 
 def _attend_einsum(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                   causal: bool, mesh) -> jnp.ndarray:
+                   causal: bool, mesh,
+                   scale: Optional[float] = None) -> jnp.ndarray:
     q = _constrain_seq(q, mesh, 1)
-    scores = _gqa_scores(q, k)
+    scores = _gqa_scores(q, k, scale)
     scores = _constrain_seq(scores, mesh, 2)
     if causal:
         Sq, Sk = scores.shape[-2], scores.shape[-1]
@@ -293,7 +300,7 @@ def attention_decode(params: Params, cfg: ModelConfig, x: jnp.ndarray,
     rows = jnp.arange(B)
     cache_k = cache_k.at[rows, index].set(k[:, 0].astype(cache_k.dtype))
     cache_v = cache_v.at[rows, index].set(v[:, 0].astype(cache_v.dtype))
-    scores = _gqa_scores(q, cache_k)                     # [B,H,1,S]
+    scores = _gqa_scores(q, cache_k, cfg.attn_scale)     # [B,H,1,S]
     valid = jnp.arange(S)[None, :] <= index[:, None]     # [B,S]
     scores = jnp.where(valid[:, None, None, :], scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1)

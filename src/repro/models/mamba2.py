@@ -1,8 +1,12 @@
-"""Mamba-2 (SSD) block — the zamba2 backbone layer.
+"""Mamba-2 (SSD) mixer: the zamba2 backbone layer and Granite 4.0-H's
+recurrent mixer.
 
 Pure-JAX reference: selective state-space recurrence as ``lax.scan`` over
 time (the Pallas chunked kernel in ``repro.kernels.mamba2_ssd`` implements
-the chunk-parallel SSD form for TPU).
+the chunk-parallel SSD form for TPU).  The mixer, as published
+(``norm_before_gate=False``): in_proj -> z, xBC, dt; a depthwise causal
+conv with silu over xBC; the scan with a D skip; the gated RMSNorm
+``rmsnorm(y * silu(z))`` over all inner channels; out_proj.
 
 State per layer (decode): (conv_state [B, K-1, d_conv_in], ssm_state
 [B, nheads, hd, N]).
@@ -32,16 +36,20 @@ def _dims(cfg: ModelConfig) -> Tuple[int, int, int, int]:
     return d_in, hd, nheads, N
 
 
-def mamba2_block_init(key, cfg: ModelConfig) -> Params:
+def _conv_dim(cfg: ModelConfig) -> int:
+    d_in, _, _, N = _dims(cfg)
+    return d_in + 2 * NGROUPS * N
+
+
+def mamba2_mixer_init(key, cfg: ModelConfig) -> Params:
     d = cfg.d_model
     d_in, hd, nheads, N = _dims(cfg)
     dt = dtype_of(cfg)
     ks = jax.random.split(key, 6)
-    conv_dim = d_in + 2 * NGROUPS * N
+    conv_dim = _conv_dim(cfg)
     return {
-        "ln": rmsnorm_init(cfg),
         # in_proj: x -> [z (d_in), xBC (conv_dim), dt (nheads)]
-        "w_in": _dense_init(ks[0], (d, 2 * d_in + 2 * NGROUPS * N + nheads), dt),
+        "w_in": _dense_init(ks[0], (d, d_in + conv_dim + nheads), dt),
         "conv_w": _dense_init(ks[1], (CONV_K, conv_dim), dt, scale=0.5),
         "conv_b": jnp.zeros((conv_dim,), dt),
         "A_log": jnp.zeros((nheads,), jnp.float32),       # A = -exp(A_log)
@@ -52,12 +60,15 @@ def mamba2_block_init(key, cfg: ModelConfig) -> Params:
     }
 
 
+def mamba2_block_init(key, cfg: ModelConfig) -> Params:
+    """zamba2's layer: the mixer with its own pre-norm."""
+    return {"ln": rmsnorm_init(cfg), **mamba2_mixer_init(key, cfg)}
+
+
 def _split_in(cfg: ModelConfig, proj: jnp.ndarray):
-    d_in, hd, nheads, N = _dims(cfg)
-    z = proj[..., :d_in]
-    xBC = proj[..., d_in: 2 * d_in + 2 * NGROUPS * N]
-    dt = proj[..., 2 * d_in + 2 * NGROUPS * N:]
-    return z, xBC, dt
+    d_in = _dims(cfg)[0]
+    end = d_in + _conv_dim(cfg)
+    return proj[..., :d_in], proj[..., d_in:end], proj[..., end:]
 
 
 def _causal_conv(xBC: jnp.ndarray, conv_state: jnp.ndarray,
@@ -85,9 +96,11 @@ def _ssd_scan(x, dt, A, B, C, D, state):
     def step(s, inp):
         xt, dtt, Bt, Ct = inp          # [B,H,hd], [B,H], [B,N], [B,N]
         da = jnp.exp(dtt * A)          # [B,H]
-        dBx = jnp.einsum("bh,bhp,bn->bhpn", dtt, xt, Bt)
+        # products and sums in float32 on the vector units, not the MXU
+        dBx = (dtt[..., None] * xt)[..., None] * Bt[:, None, None, :]
         s = da[..., None, None] * s + dBx
-        yt = jnp.einsum("bhpn,bn->bhp", s, Ct) + D[None, :, None] * xt
+        yt = jnp.sum(s * Ct[:, None, None, :], axis=-1) \
+            + D[None, :, None] * xt
         return s, yt
 
     xs = jnp.moveaxis(x, 1, 0)
@@ -98,12 +111,12 @@ def _ssd_scan(x, dt, A, B, C, D, state):
     return jnp.moveaxis(ys, 0, 1), state
 
 
-def mamba2_block(p: Params, cfg: ModelConfig, x: jnp.ndarray, state: Tuple):
-    """x: [B,S,d]; state: (conv_state, ssm_state)."""
+def mamba2_mixer(p: Params, cfg: ModelConfig, h: jnp.ndarray, state: Tuple):
+    """The mixer alone, on normed inputs h: [B,S,d]; state: (conv_state,
+    ssm_state).  Returns (out [B,S,d], new state)."""
     conv_state, ssm_state = state
-    B_, S, d = x.shape
+    B_, S, d = h.shape
     d_in, hd, nheads, N = _dims(cfg)
-    h = rmsnorm(p["ln"], x, cfg.norm_eps)
     proj = jnp.einsum("bsd,de->bse", h, p["w_in"])
     z, xBC, dt_raw = _split_in(cfg, proj)
     xBC, conv_state = _causal_conv(xBC, conv_state, p["conv_w"], p["conv_b"])
@@ -115,18 +128,24 @@ def mamba2_block(p: Params, cfg: ModelConfig, x: jnp.ndarray, state: Tuple):
     y, ssm_state = _ssd_scan(
         xin.astype(jnp.float32), dt, A, Bmat, Cmat, p["D"], ssm_state
     )
-    y = y.reshape(B_, S, d_in).astype(x.dtype)
-    y = rmsnorm(p["ln_out"], y, cfg.norm_eps)
-    y = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
+    y = y.reshape(B_, S, d_in) * jax.nn.silu(z.astype(jnp.float32))
+    y = rmsnorm(p["ln_out"], y, cfg.norm_eps).astype(h.dtype)
     out = jnp.einsum("bse,ed->bsd", y, p["w_out"])
-    return x + out, (conv_state, ssm_state)
+    return out, (conv_state, ssm_state)
+
+
+def mamba2_block(p: Params, cfg: ModelConfig, x: jnp.ndarray, state: Tuple):
+    """zamba2's layer, x + mixer(rmsnorm(x)); x: [B,S,d]; state: (conv_state,
+    ssm_state)."""
+    h = rmsnorm(p["ln"], x, cfg.norm_eps)
+    out, state = mamba2_mixer(p, cfg, h, state)
+    return x + out, state
 
 
 def mamba2_init_state(cfg: ModelConfig, batch: int, dtype=None):
     d_in, hd, nheads, N = _dims(cfg)
-    conv_dim = d_in + 2 * NGROUPS * N
     dt = dtype or jnp.dtype(cfg.dtype)
     return (
-        jnp.zeros((batch, CONV_K - 1, conv_dim), dt),
+        jnp.zeros((batch, CONV_K - 1, _conv_dim(cfg)), dt),
         jnp.zeros((batch, nheads, hd, N), jnp.float32),
     )

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import telemetry
 from ..models import Model
 
 __all__ = ["FeatureView", "ServeEngine", "GenerationResult"]
@@ -97,9 +98,16 @@ def _shared_decode_step(model: Model, mesh):
             # cached value would pin the weak key forever and leak every
             # model/executable pair for the process lifetime.  Callers of
             # fn (engines) hold the model, so the deref cannot dangle.
+            # A layer-list step carries its state through the layer scan
+            # and rewrites it in place, so its cache is donated: undonated,
+            # each step allocated a second whole cache, and near a full chip
+            # the allocator stalled steps for 0.1-1 s.  The dense scan reads
+            # the cache as scan inputs and writes it as outputs; donated,
+            # the compiler copies it whole twice more and the step is slower.
             model_ref = weakref.ref(model)
             fn = jax.jit(
-                lambda p, c, b: model_ref().decode_step(p, c, b, mesh))
+                lambda p, c, b: model_ref().decode_step(p, c, b, mesh),
+                donate_argnums=(1,) if model.cfg.layer_types else ())
             per_model[key] = fn
         return fn
 
@@ -129,6 +137,21 @@ def _row_reset(model: Model, max_context: int):
     return jax.jit(reset, donate_argnums=(0,))
 
 
+# What each cache leaf holds, for the ``serve.cache_bytes`` gauge: keys and
+# values that grow with position, or Mamba-2 state that a row carries.
+_CACHE_KIND = {"k": "kv", "v": "kv", "ssm": "ssm_state", "conv": "conv_state"}
+
+
+def _record_cache(cache: Dict[str, Any]) -> None:
+    """Set ``serve.cache_bytes{kind}`` from the cache's leaves."""
+    held: Dict[str, int] = {}
+    for name, kind in _CACHE_KIND.items():
+        if name in cache:
+            held[kind] = held.get(kind, 0) + cache[name].nbytes
+    for kind, nbytes in held.items():
+        telemetry.gauge("serve.cache_bytes", kind=kind).set(nbytes)
+
+
 @dataclass
 class _Slot:
     request: Optional[GenerationResult] = None
@@ -155,6 +178,7 @@ class ServeEngine:
         self._next_id = 0
         self._slots = [_Slot() for _ in range(batch_size)]
         self.cache = model.init_cache(batch_size, max_context)
+        _record_cache(self.cache)
         self._tokens = np.zeros((batch_size, 1), np.int32)
         self._step = _shared_decode_step(model, mesh)
         self._reset = _row_reset(model, max_context)
@@ -216,6 +240,9 @@ class ServeEngine:
                 fresh[i] = True
         if fresh.any():
             self.cache = self._reset(self.cache, jnp.asarray(fresh))
+            if "ssm" in self.cache or "conv" in self.cache:
+                telemetry.counter("serve.state_rows_reset").inc(
+                    int(fresh.sum()))
 
     def _decode_one_step(self, done: List[GenerationResult]) -> None:
         # a row samples once its last prompt token is fed; until then the

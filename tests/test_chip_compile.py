@@ -1,7 +1,8 @@
 """Compile for a described TPU v5e, with no chip attached: the Pallas
 kernels at the widths of the registered configs that would call them, and
-the qwen2-1.5b decode step at full width (depth cut to 2 layers).  What the
-chip's compiler refuses fails here, at no chip time.
+the qwen2-1.5b decode step at full width (depth cut to 2 layers), and the
+granite-4.0-h-micro decode step whole.  What the chip's compiler refuses,
+or cannot fit, fails here, at no chip time.
 
 The topology is described only inside the fixture: only one process at a
 time may load the TPU library, so no module may do it while it is imported.
@@ -82,3 +83,25 @@ def test_qwen2_decode_step_compiles_for_v5e(v5e_chip):
         {"token": _on(v5e_chip, token)}).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_granite_decode_step_fits_one_v5e(v5e_chip):
+    """granite-4.0-h-micro at full width and depth, as the serve cell runs
+    it: 32 rows of 2048 positions, the cache donated as ``ServeEngine``
+    donates it.  The whole cache is rewritten in place, and the weights,
+    the cache and the step's temporaries leave room on the chip's 16 GiB
+    for the logits and the engine's row reset."""
+    model = build_model(get_config("granite-4.0-h-micro"))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(32, 2048))
+    token = jax.ShapeDtypeStruct((32, 1), jnp.int32)
+    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        _on(v5e_chip, params), _on(v5e_chip, cache),
+        {"token": _on(v5e_chip, token)}).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes   # the chip pads ``index``
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes < 11e9
+    assert mem.temp_size_in_bytes < 1e9         # the state is not copied

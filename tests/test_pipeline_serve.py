@@ -126,6 +126,25 @@ def test_serve_engine_rows_are_independent():
         assert by_id[rid].tokens == seq[len(prompt):], rid
 
 
+@pytest.mark.parametrize("name, donated", [("granite-4.0-h-micro", True),
+                                           ("qwen2-1.5b", False)])
+def test_serve_engine_decode_step_donates_the_cache(name, donated):
+    """A layer-list step rewrites the cache in place: the cache it was
+    handed is consumed, so the engine never holds two whole caches at once.
+    The dense step keeps its cache undonated."""
+    model = build_model(get_config(name).reduced())
+    eng = ServeEngine(model, model.init(jax.random.PRNGKey(0)),
+                      batch_size=2, max_context=16, eos_token=-1)
+    eng.submit([1, 2], max_new_tokens=1)
+    eng._fill_slots()
+    before = eng.cache
+    eng._decode_one_step([])
+    assert all(leaf.is_deleted() == donated
+               for leaf in jax.tree_util.tree_leaves(before))
+    assert not any(leaf.is_deleted()
+                   for leaf in jax.tree_util.tree_leaves(eng.cache))
+
+
 def test_serve_engine_rejects_overlong_request():
     cfg = get_config("qwen2-1.5b").reduced()
     model = build_model(cfg)
