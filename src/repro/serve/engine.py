@@ -79,16 +79,20 @@ class FeatureView:
 
 
 # One jitted decode step per (model, mesh): engines over the same model reuse
-# one compiled executable instead of re-jitting a fresh lambda each time.
-# Besides skipping the recompile, this pins determinism — two executables
-# compiled from identical HLO may still autotune differently, and a
-# low-order-bit logit difference is enough to flip a greedy argmax tie
-# (the test_serve_engine_greedy_deterministic flake).
+# one compiled executable (one greedy, one sampled) instead of re-jitting a
+# fresh function each time.  Besides skipping the recompile, this pins
+# determinism — two executables compiled from identical HLO may still
+# autotune differently, and a low-order-bit logit difference is enough to
+# flip a greedy argmax tie (the test_serve_engine_greedy_deterministic flake).
 _STEP_CACHE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 _STEP_LOCK = threading.Lock()
 
 
 def _shared_decode_step(model: Model, mesh):
+    """jit(params, cache, batch) -> (next token [B] int32, cache), greedy;
+    jit(params, cache, batch, key, temperature) -> (next token, cache, next
+    key), sampled.  The token is chosen on the device, so the host pulls B
+    ids and never the [B, 1, V] logits."""
     with _STEP_LOCK:
         per_model = _STEP_CACHE.setdefault(model, {})
         key = id(mesh)  # mesh stays alive via the jitted closure below
@@ -98,16 +102,28 @@ def _shared_decode_step(model: Model, mesh):
             # cached value would pin the weak key forever and leak every
             # model/executable pair for the process lifetime.  Callers of
             # fn (engines) hold the model, so the deref cannot dangle.
+            model_ref = weakref.ref(model)
+
+            def step(params, cache, batch, rng=None, temperature=None):
+                logits, cache = model_ref().decode_step(params, cache, batch,
+                                                        mesh)
+                logits = logits[:, 0, :]
+                if rng is None:  # greedy: the argmax, lowest index on ties
+                    return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+                # Gumbel-max: a sample of softmax(logits / temperature)
+                rng, sub = jax.random.split(rng)
+                noise = jax.random.gumbel(sub, logits.shape)
+                scores = logits.astype(jnp.float32) / temperature + noise
+                return jnp.argmax(scores, axis=-1).astype(jnp.int32), cache, rng
+
             # A layer-list step carries its state through the layer scan
             # and rewrites it in place, so its cache is donated: undonated,
             # each step allocated a second whole cache, and near a full chip
             # the allocator stalled steps for 0.1-1 s.  The dense scan reads
             # the cache as scan inputs and writes it as outputs; donated,
             # the compiler copies it whole twice more and the step is slower.
-            model_ref = weakref.ref(model)
-            fn = jax.jit(
-                lambda p, c, b: model_ref().decode_step(p, c, b, mesh),
-                donate_argnums=(1,) if model.cfg.layer_types else ())
+            fn = jax.jit(step,
+                         donate_argnums=(1,) if model.cfg.layer_types else ())
             per_model[key] = fn
         return fn
 
@@ -174,6 +190,7 @@ class ServeEngine:
         self.temperature = temperature
         self.mesh = mesh
         self._rng = jax.random.PRNGKey(seed)
+        self._temperature = jnp.float32(temperature)
         self._queue: "queue.Queue[GenerationResult]" = queue.Queue()
         self._next_id = 0
         self._slots = [_Slot() for _ in range(batch_size)]
@@ -258,16 +275,15 @@ class ServeEngine:
         # be reading it after dispatch returns, and _tokens is rewritten
         # before the next step (jnp.array does not copy a numpy input first)
         batch = {"token": jnp.asarray(self._tokens.copy())}
-        logits, self.cache = self._step(self.params, self.cache, batch)
+        if self.temperature > 0:
+            nxt, self.cache, self._rng = self._step(
+                self.params, self.cache, batch, self._rng, self._temperature)
+        else:
+            nxt, self.cache = self._step(self.params, self.cache, batch)
         if not sampling.any():
             return
-        logits = np.asarray(logits[:, 0, :], np.float32)
-        if self.temperature > 0:
-            self._rng, sub = jax.random.split(self._rng)
-            noise = np.asarray(jax.random.gumbel(sub, logits.shape))
-            nxt = np.argmax(logits / self.temperature + noise, axis=-1)
-        else:
-            nxt = np.argmax(logits, axis=-1)
+        nxt = np.asarray(nxt)
+        telemetry.counter("serve.host_pull_bytes").inc(nxt.nbytes)
         for i, slot in enumerate(self._slots):
             if not sampling[i]:
                 continue

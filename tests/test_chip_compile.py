@@ -1,8 +1,9 @@
 """Compile for a described TPU v5e, with no chip attached: the Pallas
 kernels at the widths of the registered configs that would call them, and
-the qwen2-1.5b decode step at full width (depth cut to 2 layers), and the
-granite-4.0-h-micro decode step whole.  What the chip's compiler refuses,
-or cannot fit, fails here, at no chip time.
+the qwen2-1.5b decode step and the serving engine's step around it at full
+width (depth cut to 2 layers), and the granite-4.0-h-micro decode step
+whole.  What the chip's compiler refuses, or cannot fit, fails here, at no
+chip time.
 
 The topology is described only inside the fixture: only one process at a
 time may load the TPU library, so no module may do it while it is imported.
@@ -19,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.cases import WIDTHS, kernel_case
 from repro.kernels.flashattn.ops import attention
 from repro.models import build_model, get_config
+from repro.serve.engine import _shared_decode_step
 
 
 @pytest.fixture(scope="module")
@@ -83,6 +85,26 @@ def test_qwen2_decode_step_compiles_for_v5e(v5e_chip):
         {"token": _on(v5e_chip, token)}).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_qwen2_engine_step_hands_out_token_ids_for_v5e(v5e_chip):
+    """The serving engine's greedy step for qwen2-1.5b at the serve cell's
+    64 rows x 2048 (depth cut to 2): it chooses the token on the chip and
+    hands out the [64] ids and the cache, with no [64, 1, 151936] logits."""
+    cfg = dataclasses.replace(get_config("qwen2-1.5b"), n_layers=2)
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(64, 2048))
+    token = jax.ShapeDtypeStruct((64, 1), jnp.int32)
+    lowered = _shared_decode_step(model, None).lower(
+        _on(v5e_chip, params), _on(v5e_chip, cache),
+        {"token": _on(v5e_chip, token)})
+    ids, _ = lowered.out_info
+    assert (ids.shape, ids.dtype) == ((64,), jnp.int32)
+    mem = lowered.compile().memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(cache))
+    assert mem.output_size_in_bytes < cache_bytes + 1e6
 
 
 def test_granite_decode_step_fits_one_v5e(v5e_chip):

@@ -118,41 +118,46 @@ def _counter(name, **labels):
     return telemetry.registry().snapshot()["counters"].get(key, 0)
 
 
-def _recording(engine):
-    """Keep the logits of every step the engine runs."""
-    seen, step = [], engine._step
+def _recording(model):
+    """``model`` with a decode step that keeps every logit it computes, in
+    the order its steps run: the engine's compiled step chooses the token on
+    the device and hands the host no logits."""
+    seen = []
 
-    def run(params, cache, batch):
-        logits, cache = step(params, cache, batch)
-        seen.append(np.asarray(logits))
+    def decode_step(params, cache, batch, mesh=None):
+        logits, cache = model.decode_step(params, cache, batch, mesh)
+        jax.debug.callback(lambda x: seen.append(np.array(x)), logits,
+                           ordered=True)
         return logits, cache
 
-    engine._step = run
-    return seen
+    return replace(model, decode_step=decode_step), seen
 
 
 def test_refilled_row_serves_as_a_fresh_engine():
     """A row reused after a finished request starts from zero recurrent
     state: every logit of the second request equals, bit for bit, that of an
     engine that never served the first."""
-    model = _model()
+    model, seen = _recording(_model())
     params = REF.init_params(TINY, SEED)
     first, second = _tokens(1, 10, 7)[0].tolist(), _tokens(1, 6, 9)[0].tolist()
     reused = ServeEngine(model, params, batch_size=1, max_context=48,
                          eos_token=-1)
-    seen = _recording(reused)
     resets = _counter("serve.state_rows_reset")
     reused.submit(first, max_new_tokens=12)
     reused.submit(second, max_new_tokens=24)
     out = {r.request_id: r.tokens for r in reused.run(max_steps=100)}
     assert _counter("serve.state_rows_reset") - resets == 2
+    jax.effects_barrier()
+    n = len(seen)
     fresh = ServeEngine(model, params, batch_size=1, max_context=48,
                         eos_token=-1)
-    want = _recording(fresh)
     fresh.submit(second, max_new_tokens=24)
     assert out[1] == fresh.run(max_steps=100)[0].tokens
-    assert len(seen) == 21 + len(want) and len(want) == 6 + 24 - 1
-    assert all(np.array_equal(a, b) for a, b in zip(seen[21:], want))
+    jax.effects_barrier()
+    want = seen[n:]
+    assert n == 21 + len(want) and len(want) == 6 + 24 - 1
+    assert all(a.shape == (1, 1, TINY["vocab_size"]) for a in seen)
+    assert all(np.array_equal(a, b) for a, b in zip(seen[21:n], want))
     ssm, conv = REF._state_bytes(REF.dims(TINY))
     gauges = telemetry.registry().snapshot()["gauges"]
     assert gauges["serve.cache_bytes{kind=ssm_state}"] == ssm

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import telemetry
 from repro.core.datapipe import PipeConfig
 from repro.models import build_model, get_config
 from repro.pipeline import PipeFeeder, SyntheticSource
@@ -143,6 +144,60 @@ def test_serve_engine_decode_step_donates_the_cache(name, donated):
                for leaf in jax.tree_util.tree_leaves(before))
     assert not any(leaf.is_deleted()
                    for leaf in jax.tree_util.tree_leaves(eng.cache))
+
+
+@pytest.mark.parametrize("temperature", [0.0, 1.0])
+def test_serve_engine_step_returns_token_ids(temperature):
+    """The compiled step chooses the next token: its first output is the
+    [B] int32 ids, and no [B, 1, V] logits leave it."""
+    model = build_model(get_config("qwen2-1.5b").reduced())
+    eng = ServeEngine(model, model.init(jax.random.PRNGKey(0)),
+                      batch_size=3, max_context=16, temperature=temperature)
+    args = (eng._rng, eng._temperature) if temperature else ()
+    ids, *rest = eng._step(eng.params, eng.cache,
+                           {"token": jnp.ones((3, 1), jnp.int32)}, *args)
+    assert ids.shape == (3,) and ids.dtype == jnp.int32
+    # the rest is the cache and, when sampling, the next key
+    assert jax.tree_util.tree_structure(rest) == \
+        jax.tree_util.tree_structure([eng.cache, *args[:1]])
+
+
+def test_serve_engine_counts_host_pull_bytes():
+    """A step pulls B int32 ids once a row samples, and nothing while every
+    row is still being fed its prompt."""
+    model = build_model(get_config("qwen2-1.5b").reduced())
+    eng = ServeEngine(model, model.init(jax.random.PRNGKey(0)),
+                      batch_size=4, max_context=16, eos_token=-1)
+    eng.submit([1, 2, 3], max_new_tokens=2)
+    eng._fill_slots()
+
+    def pulled():
+        counter = telemetry.counter("serve.host_pull_bytes")
+        before = counter.value
+        eng._decode_one_step([])
+        return counter.value - before
+
+    assert [pulled() for _ in range(4)] == [0, 0, 4 * 4, 4 * 4]
+
+
+def test_serve_engine_sampling_is_seeded():
+    """Sampling is Gumbel-max on the device: one seed gives one stream of
+    tokens, and at a high temperature that stream is not the greedy one."""
+    model = build_model(get_config("qwen2-1.5b").reduced())
+    params = model.init(jax.random.PRNGKey(0))
+
+    def tokens(temperature, seed=7):
+        eng = ServeEngine(model, params, batch_size=2, max_context=32,
+                          eos_token=-1, temperature=temperature, seed=seed)
+        for prompt in ([5, 6], [9, 1, 4]):
+            eng.submit(prompt, max_new_tokens=12)
+        return [r.tokens for r in sorted(eng.run(max_steps=50),
+                                         key=lambda r: r.request_id)]
+
+    sampled = tokens(1.0)
+    assert sampled == tokens(1.0)
+    assert tokens(100.0) != tokens(0.0)
+    assert all(0 <= t < model.cfg.vocab for row in sampled for t in row)
 
 
 def test_serve_engine_rejects_overlong_request():
