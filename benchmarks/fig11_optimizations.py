@@ -38,21 +38,14 @@ RUNGS = [
     ("ioredirect", PipeConfig(mode="text")),
     ("binary", PipeConfig(mode="parts")),
     ("delim_removed", PipeConfig(mode="binary_rows")),
-    # the pre-zero-copy transfer path: per-row text serialization into the
-    # assembler, concatenated single-buffer frames, strictly serial send
-    ("pipegen_seedpath", PipeConfig(mode="arrowcol", pipelined=False,
-                                    scatter_gather=False, block_export=False)),
     # full PipeGen: typed block export, pooled zero-copy scatter-gather
     # encode, vectored send, double-buffered pipelined sender
     ("pipegen_full", PipeConfig(mode="arrowcol")),
     # same data plane over the in-process channel (one materialization at
     # the queue boundary) and over the shared-memory ring (in-place spans,
-    # zero intermediate copies, works across OS processes).  The shm rung
-    # is pinned to the backoff-POLL wait path so it doubles as the
-    # baseline the event-driven doorbell rung is measured against.
+    # zero intermediate copies, works across OS processes)
     ("pipegen_channel", PipeConfig(mode="arrowcol", transport="channel")),
-    ("pipegen_shm", PipeConfig(mode="arrowcol", transport="shm",
-                               shm_doorbell=False)),
+    ("pipegen_shm", PipeConfig(mode="arrowcol", transport="shm")),
 ]
 
 
@@ -143,64 +136,6 @@ def _streams_sweep(n_rows: int, transports, streams_list) -> dict:
         emit("fig11.streams_link_speedup",
              out[("link", lo)] - out[("link", hi)],
              f"speedup={out[('link', lo)] / out[('link', hi)]:.2f}x")
-    return out
-
-
-def _doorbell_probe(n_rows: int) -> dict:
-    """Event-driven doorbell vs backoff polling, measured as what the
-    doorbell actually changes: the **latency of a small transfer that
-    arrives while the reader is parked idle**.  Each round sends one
-    timestamp-stamped frame after an idle gap and measures publication →
-    delivery.  A polled reader has backed off to the 2 ms idle cap by
-    then, so its wake is late by up to a whole sleep quantum (medians
-    1-2 ms with a fat tail); the doorbell is rung on commit and wakes in
-    the sub-millisecond range, every time.  (End-to-end *throughput* of
-    warm transfers is deliberately not the metric here: polling's
-    overshoot is bounded by the cap, so bulk wall-clock ties — the
-    pipegen_shm rung above covers that regime.)"""
-    import statistics
-    import struct
-
-    from repro.core.shm_ring import ShmRing, ShmRingTransport
-    from repro.core.transport import FRAME_EOF as _EOF, FRAME_TEXT as _TXT
-
-    def wake_lats(doorbell: bool, rounds: int = 21,
-                  idle_s: float = 0.012) -> list:
-        ring = ShmRing.create(capacity=1 << 20, role="reader",
-                              doorbell=doorbell)
-        tx, rx = ShmRingTransport(ring), ShmRingTransport(ring)
-
-        def send():
-            for _ in range(rounds):
-                time.sleep(idle_s)  # the reader reaches its deep-idle wait
-                tx.send_frames(_TXT,
-                               [struct.pack("<d", time.perf_counter())])
-            tx.send_frames(_EOF, [b""])
-
-        th = threading.Thread(target=send, daemon=True)
-        th.start()
-        lats = []
-        while True:
-            kind, payload = rx.recv_frame()
-            if kind == _EOF:
-                break
-            sent = struct.unpack("<d", bytes(payload))[0]
-            lats.append(time.perf_counter() - sent)
-        th.join()
-        ring.close()
-        return sorted(lats)
-
-    out = {}
-    for name, db in (("shm_polled_wake", False), ("shm_doorbell", True)):
-        lats = wake_lats(db)
-        out[name] = statistics.median(lats)
-        out[name + "_p90"] = lats[(len(lats) * 9) // 10]
-    emit("fig11.shm_polled_wake", out["shm_polled_wake"],
-         f"idle-wake latency p90={out['shm_polled_wake_p90'] * 1e3:.2f}ms")
-    emit("fig11.shm_doorbell", out["shm_doorbell"],
-         f"idle-wake latency p90={out['shm_doorbell_p90'] * 1e3:.2f}ms "
-         f"speedup_vs_polled="
-         f"{out['shm_polled_wake'] / out['shm_doorbell']:.2f}x")
     return out
 
 
@@ -599,8 +534,8 @@ def _incremental_probe(n_rows: int) -> dict:
 
 
 def _telemetry_probe(n_rows: int, baseline: float = 0.0) -> dict:
-    """Observability tax rung: the arrowcol shm transfer (polled wait
-    path, same shape as the ``pipegen_shm`` rung) with telemetry left
+    """Observability tax rung: the arrowcol shm transfer (same shape as
+    the ``pipegen_shm`` rung) with telemetry left
     OFF vs fully ON (span tracer enabled, ``trace=True`` pipes).  The
     disabled path is the contract — ``span()`` must collapse to one
     module-attribute load and a null context manager — so the figure is
@@ -611,9 +546,8 @@ def _telemetry_probe(n_rows: int, baseline: float = 0.0) -> dict:
     from repro.core import disable_tracing, enable_tracing
     from repro.core.telemetry import tracer
 
-    cfg = PipeConfig(mode="arrowcol", transport="shm", shm_doorbell=False)
-    tcfg = PipeConfig(mode="arrowcol", transport="shm", shm_doorbell=False,
-                      trace=True)
+    cfg = PipeConfig(mode="arrowcol", transport="shm")
+    tcfg = PipeConfig(mode="arrowcol", transport="shm", trace=True)
 
     pipe_transfer("colstore", "graphstore", n_rows, cfg)  # warm
     out = {"telemetry_off": float("inf"), "telemetry_on": float("inf")}
@@ -670,10 +604,6 @@ def main(n_rows: int = DEFAULT_ROWS, transports=None, streams_sweep=None) -> dic
         tp = pipe_transfer("colstore", "graphstore", n_rows, cfg)
         out[name] = tp
         emit(f"fig11.{name}", tp, f"speedup={tf / tp:.2f}x")
-    # the zero-copy + pipelined win, measured (not asserted): full PipeGen
-    # vs. the seed transfer path on the same machine/block
-    emit("fig11.pipegen_vs_seedpath", out["pipegen_seedpath"] - out["pipegen_full"],
-         f"speedup={out['pipegen_seedpath'] / out['pipegen_full']:.2f}x")
     # acceptance probe: the cross-process-capable shm ring should at least
     # match the in-process channel on colocated transfers.  Single samples
     # swing +-30% on small CI boxes, so refine both with two more
@@ -687,9 +617,7 @@ def main(n_rows: int = DEFAULT_ROWS, transports=None, streams_sweep=None) -> dic
         emit(f"fig11.{name}_best3", out[name], f"speedup={tf / out[name]:.2f}x")
     emit("fig11.shm_vs_channel", out["pipegen_channel"] - out["pipegen_shm"],
          f"ratio={out['pipegen_channel'] / out['pipegen_shm']:.2f}x")
-    # event-driven wakeups vs polling (latency-bound small transfer) and
     # the fan-out broadcast ring (one encode feeding three importers)
-    out["doorbell"] = _doorbell_probe(n_rows)
     out["broadcast"] = _broadcast_probe(n_rows)
     # self-healing transfers: resumed retry vs full re-run after a
     # mid-stream importer death on a bandwidth-capped edge

@@ -17,7 +17,6 @@ import time
 from repro.core import PipeConfig
 from repro.core.datapipe import DataPipeInput, DataPipeOutput
 from repro.core.directory import WorkerDirectory, set_directory
-from repro.core.iobuf import BufferPool, DecodeArena
 from repro.engines import make_paper_block
 
 from .common import DEFAULT_ROWS, emit, pipe_transfer
@@ -25,20 +24,22 @@ from .common import DEFAULT_ROWS, emit, pipe_transfer
 SIZES = [64, 256, 1024, 4096, 16384, 65536]
 
 
-def _stream_decode(n_rows: int, block_rows: int, arena: DecodeArena) -> float:
-    """Streaming importer profile: blocks are dropped as consumed, which is
-    the lifecycle the decode arena accelerates (a bulk engine import holds
-    every block until the final merge, so its stores cannot recycle until
-    the stream ends — by design, not by accident)."""
+def _stream(n_rows: int, block_rows: int, strings: bool = False):
+    """One arrowcol pipe into a streaming importer; returns the transfer's
+    seconds and both ends' ``PipeStats``.  Blocks are dropped as consumed,
+    which is the lifecycle the decode arena accelerates (a bulk engine
+    import holds every block until the final merge, so its stores cannot
+    recycle until the stream ends — by design, not by accident)."""
     set_directory(WorkerDirectory())
-    name = f"db://fig14-decode-{block_rows}?query=1"
-    block = make_paper_block(n_rows, seed=1)
-    rows = []
+    name = f"db://fig14-stream-{block_rows}?query=1"
+    block = make_paper_block(n_rows, seed=1, strings=strings)
+    got = {}
 
     def imp():
-        pipe = DataPipeInput(name, arena=arena)
-        rows.append(sum(len(b) for b in pipe.blocks()))
+        pipe = DataPipeInput(name)
+        got["rows"] = sum(len(b) for b in pipe.blocks())
         pipe.close()
+        got["stats"] = pipe.stats
 
     t = threading.Thread(target=imp, daemon=True)
     t.start()
@@ -48,8 +49,8 @@ def _stream_decode(n_rows: int, block_rows: int, arena: DecodeArena) -> float:
     out.write_block(block)
     out.close()
     t.join(120)
-    assert rows and rows[0] == n_rows
-    return time.perf_counter() - t0
+    assert got.get("rows") == n_rows
+    return time.perf_counter() - t0, out.stats, got["stats"]
 
 
 def main(n_rows: int = DEFAULT_ROWS) -> dict:
@@ -61,28 +62,27 @@ def main(n_rows: int = DEFAULT_ROWS) -> dict:
         out[rows] = t
         emit(f"fig14.block_rows_{rows}", t)
     # pooled-buffer efficiency: string columns exercise the pooled offsets
-    # path every block, so the hit rate shows reuse vs. block size
+    # path every block, so the exporter's hit rate shows reuse vs. block
+    # size
     for rows in SIZES:
-        pool = BufferPool()
-        t = pipe_transfer("colstore", "dataframe", n_rows,
-                          PipeConfig(mode="arrowcol", block_rows=rows,
-                                     pool=pool), strings=True)
+        t, exp, _ = _stream(n_rows, rows, strings=True)
         out[f"strings_{rows}"] = t
-        s = pool.stats
-        total = s.hits + s.misses
-        rate = (s.hits / total) if total else 0.0
+        total = exp.pool_hits + exp.pool_misses
+        rate = (exp.pool_hits / total) if total else 0.0
         emit(f"fig14.strings_block_rows_{rows}", t,
              f"pool_hit_rate={rate:.2f} acquires={total}")
     # decode-arena efficiency: the importer-side mirror of the sweep above,
     # measured on a streaming consumer (the arena's target lifecycle)
     for rows in SIZES:
-        arena = DecodeArena(BufferPool())
         # use the function's own transfer-only timing (it excludes block
-        # construction and thread spin-up), best of two like timed()
-        t = min(_stream_decode(n_rows, rows, arena) for _ in range(2))
+        # construction and thread spin-up), best of two like timed(); the
+        # hit rate is the second, warm run's
+        runs = [_stream(n_rows, rows) for _ in range(2)]
+        t = min(r[0] for r in runs)
         out[f"decode_{rows}"] = t
-        total = arena.hits + arena.misses
-        rate = (arena.hits / total) if total else 0.0
+        imp = runs[-1][2]
+        total = imp.decode_pool_hits + imp.decode_pool_misses
+        rate = (imp.decode_pool_hits / total) if total else 0.0
         emit(f"fig14.decode_block_rows_{rows}", t,
              f"decode_hit_rate={rate:.2f} acquires={total}")
     # transport sweep at a frame-heavy block size: socket pays the kernel

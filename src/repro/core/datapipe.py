@@ -48,6 +48,7 @@ from .shm_ring import (
     DEFAULT_RING_CAPACITY,
     ShmRing,
     ShmRingTransport,
+    acquire_broadcast_ring,
     acquire_ring,
     attach_ring,
 )
@@ -59,7 +60,6 @@ from .formopt import (
     render_json,
 )
 from .stream import (
-    DEFAULT_STREAM_WINDOW,
     FaninTransport,
     StripedReceiver,
     StripedSender,
@@ -145,27 +145,19 @@ def is_reserved(filename: str) -> bool:
 class PipeConfig:
     """Negotiated pipe behaviour; travels in the schema frame meta.
 
-    ``pipelined``/``scatter_gather``/``pool`` are exporter-local transport
-    knobs (they do not travel in the meta): ``pipelined`` runs compression
-    and the vectored send on a bounded sender thread so encoding block N+1
-    overlaps the send of block N (the paper's producer/consumer overlap);
-    ``scatter_gather`` disables the zero-copy path when False, falling back
-    to the concatenate-then-send profile (kept for the fig. 11 seed-path
-    comparison); ``pool`` supplies a dedicated buffer pool (default: the
-    process-wide pool).
+    Both ends take the same object: ``DataPipeOutput`` reads the exporter's
+    fields, ``DataPipeInput`` the importer's, and each ignores the rest.
+    ``mode``/``codec``/``text_format``/``delimiter``/``verify_first_n`` are
+    the exporter's wire decisions and travel in the schema meta;
+    ``block_rows`` sets its frame size.
 
-    ``transport``/``shm_capacity``/``decode_arena`` are importer-local: the
+    ``transport``/``shm_capacity``/``broadcast`` are importer-local: the
     importer picks the rendezvous flavor (it registers the endpoint, the way
     it owns the listening socket), the exporter connects to whatever kind
     the directory hands back.  ``transport`` is one of ``socket`` (TCP,
     default), ``channel`` (in-process queue) or ``shm`` (cross-process
-    shared-memory ring, zero intermediate copies); ``decode_arena`` supplies
-    a dedicated :class:`~repro.core.iobuf.DecodeArena` so decode pool stats
-    attribute to one pipe (default: a per-pipe arena over the process-wide
-    decode pool).  ``shm_doorbell`` (importer-local, on by default) gives
-    the ring a fifo/eventfd doorbell so a blocked side wakes in
-    microseconds; off (or on doorbell-less platforms) it falls back to the
-    exponential-backoff poll.  ``broadcast`` (importer-local, shm only)
+    shared-memory ring, zero intermediate copies; it gets a fifo/eventfd
+    doorbell wherever the platform has one).  ``broadcast`` (shm only)
     joins this pipe as one of N readers of a *broadcast ring*: the
     exporter encodes and publishes every frame once and all N colocated
     importers consume it from the same segment (the planner sets this on
@@ -175,9 +167,9 @@ class PipeConfig:
     ``streams`` (importer-local) stripes each pipe across N member
     connections of the chosen transport flavor — the importer registers a
     multi-endpoint group, the exporter's frames spread round-robin over the
-    members and reassemble in sequence order behind a ``stream_window``-
-    frame reorder window with per-stream credits.  ``partition`` (exporter-
-    local) turns the transfer into an N→M shuffle: every exporter worker
+    members and reassemble in sequence order behind a reorder window with
+    per-stream credits.  ``partition`` (exporter-local) turns the
+    transfer into an N→M shuffle: every exporter worker
     routes rows to *all* import workers by key (``hash[:col]``,
     ``range[:col]``, ``rr``); ``partition_bounds`` presets the range
     split points (the planner's global compile-time quantiles, stamped
@@ -196,18 +188,10 @@ class PipeConfig:
     verify_first_n: int = 0  # probabilistic runtime check (section 4.1)
     link: Optional[LinkSim] = None
     connect_timeout: float = 30.0
-    pipelined: bool = True  # double-buffered sender thread
-    scatter_gather: bool = True  # zero-copy vectored send
-    sender_depth: int = 2  # bounded in-flight frames (double buffering)
-    block_export: bool = True  # allow exporters to hand over whole blocks
-    pool: Optional[BufferPool] = None
     transport: str = "socket"  # socket | channel | shm (importer-side)
     shm_capacity: int = DEFAULT_RING_CAPACITY  # ring data-region bytes
-    shm_doorbell: bool = True  # fifo/eventfd wakeups (False = backoff poll)
     broadcast: int = 0  # shm fan-out: join as one of N broadcast readers
-    decode_arena: Optional[DecodeArena] = None  # importer-side decode pool
     streams: int = 1  # stripe each pipe across N member connections
-    stream_window: int = DEFAULT_STREAM_WINDOW  # reorder window (frames)
     partition: Optional[str] = None  # N→M shuffle: hash[:col]|range[:col]|rr
     partition_bounds: Optional[Tuple] = None  # preset global range bounds
     fanin: int = 1  # importer-side: exporter streams to merge (shuffle)
@@ -227,12 +211,10 @@ class PipeConfig:
     # into span recording (enabling the process tracer if needed);
     # ``trace_ctx`` is the propagated "trace_id:span_id" parent context,
     # stamped by the plan executor so both ends of an edge join one
-    # trace; ``flight_depth`` bounds the per-pipe flight-recorder ring;
-    # ``recorder`` shares the executor's per-edge FlightRecorder so pipe
-    # events land in the same timeline as admission/retry events.
+    # trace; ``recorder`` shares the executor's per-edge FlightRecorder so
+    # pipe events land in the same timeline as admission/retry events.
     trace: bool = False  # record lifecycle spans for this pipe
     trace_ctx: str = ""  # propagated parent trace context
-    flight_depth: int = 64  # flight-recorder ring depth (events)
     recorder: Optional["FlightRecorder"] = None  # shared edge recorder
 
     def meta(self) -> dict:
@@ -412,9 +394,13 @@ class _PoolHandle:
         return buf
 
 
+#: frames queued to an exporter's sender thread: double buffering
+_SENDER_DEPTH = 2
+
+
 class _PipelinedSender:
     """Bounded sender thread: compress + vectored send of frame N overlap
-    the encoding of frame N+1 (double buffering via ``depth``).
+    the encoding of frame N+1 (double buffering, ``_SENDER_DEPTH``).
 
     Error contract: a failure in compress/send is latched; subsequent
     submissions drain (releasing pooled buffers) so the producer never
@@ -424,10 +410,10 @@ class _PipelinedSender:
 
     _DONE = object()
 
-    def __init__(self, transport: Transport, codec: Codec, depth: int = 2):
+    def __init__(self, transport: Transport, codec: Codec):
         self._transport = transport
         self._codec = codec
-        self._q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
+        self._q: "queue.Queue" = queue.Queue(maxsize=_SENDER_DEPTH)
         self.busy_s = 0.0   # sender-thread time spent compressing/sending
         self.wait_s = 0.0   # producer time blocked on the bounded queue
         # (start, end) spans, each list appended in time order by one
@@ -561,7 +547,7 @@ class DataPipeOutput(_PhaseSpans):
         self._fill_t0: Optional[float] = None
         self._fill_write_s = 0.0
         self._recorder = self.config.recorder or FlightRecorder(
-            self.config.flight_depth, name=f"export {rn.dataset}")
+            name=f"export {rn.dataset}")
         self._recorder.note("export.open", dataset=rn.dataset,
                             query=rn.query_id, attempt=self.config.attempt)
         # validate codec/format before any rendezvous so a bad config fails
@@ -619,12 +605,8 @@ class DataPipeOutput(_PhaseSpans):
             self._resume_token = self.config.resume
             self._resume_from = int(getattr(endpoint, "resume_seq", 0) or 0)
             self._resume_skip_left = self._resume_from
-        self._pool = _PoolHandle(self.config.pool or default_pool())
-        self._sender: Optional[_PipelinedSender] = None
-        if self.config.pipelined:
-            self._sender = _PipelinedSender(
-                self._transport, self._codec, self.config.sender_depth
-            )
+        self._pool = _PoolHandle(default_pool())
+        self._sender = _PipelinedSender(self._transport, self._codec)
         self._parts_wire = PartsRowsFormat()
         self._text_buf: List[str] = []
         self._text_len = 0
@@ -708,12 +690,11 @@ class DataPipeOutput(_PhaseSpans):
             self._send(FRAME_EOF, SegmentList([b""]), compress=False)
         finally:
             self.closed = True
-            if self._sender is not None:
-                try:
-                    self._sender.close()
-                except BaseException as e:  # noqa: BLE001 - re-raised below
-                    sender_err = e
-                self.stats.send_overlap_s = self._sender.overlap_s
+            try:
+                self._sender.close()
+            except BaseException as e:  # noqa: BLE001 - re-raised below
+                sender_err = e
+            self.stats.send_overlap_s = self._sender.overlap_s
             # always close the transport -- a sender failure must not leave
             # the reader blocked on a half-open stream.  Close *before*
             # reading the counters: a striped sender only finishes sending
@@ -782,29 +763,17 @@ class DataPipeOutput(_PhaseSpans):
 
     def _send_impl(self, kind: bytes, segs: SegmentList,
                    compress: bool = True) -> None:
-        """Route one frame out: codec at the segment level (data frames
-        only -- schema/verify/EOF travel uncompressed), then either the
-        double-buffered sender thread (pipelined) or an inline vectored
-        send.  ``scatter_gather=False`` re-materializes the payload first,
-        reproducing the seed path's concatenate-then-send copy profile."""
+        """Route one frame out to the double-buffered sender thread, which
+        applies the codec at the segment level (data frames only --
+        schema/verify/EOF travel uncompressed) and sends it vectored."""
         if self._resume_skip_left and kind in _DATA_FRAME_KINDS:
             # the importer acked this frame on a previous attempt
             self._resume_skip_left -= 1
             self.stats.resume_skipped += 1
             segs.release()
             return
-        if not self.config.scatter_gather:
-            payload = segs.join()
-            segs.release()
-            segs = SegmentList([payload])
         self.stats.copies_avoided += segs.copies_avoided
-        if self._sender is not None:
-            self._sender.submit(kind, segs, compress)
-            return
-        if compress:
-            segs = self._codec.compress_segments(segs)
-        self._transport.send_frames(kind, segs)
-        segs.release()
+        self._sender.submit(kind, segs, compress)
 
     # -- text rung ---------------------------------------------------------------
     def _flush_text(self) -> None:
@@ -902,11 +871,8 @@ class DataPipeOutput(_PhaseSpans):
     # -- typed block fast path (decorated exporters, fig. 11 'full PipeGen') ------
     def accepts_blocks(self) -> bool:
         """True when whole ColumnBlocks can bypass the text serializer."""
-        return (
-            self.config.block_export
-            and self.config.mode not in ("text", "parts", "bytes")
-            and not self.closed
-        )
+        return (self.config.mode not in ("text", "parts", "bytes")
+                and not self.closed)
 
     def write_block(
         self,
@@ -921,11 +887,11 @@ class DataPipeOutput(_PhaseSpans):
         dialect byte-for-byte.
 
         Zero-copy ownership contract: fixed-width columns go on the wire
-        as views of ``block``'s live numpy buffers, and with
-        ``pipelined=True`` the send completes asynchronously -- the caller
-        must not mutate the block's columns until :meth:`close` returns
-        (engines hand over stored, immutable blocks, so this holds by
-        construction on every generated-adapter path)."""
+        as views of ``block``'s live numpy buffers, and every send completes
+        asynchronously on the sender thread -- the caller must not mutate
+        the block's columns until :meth:`close` returns (engines hand over
+        stored, immutable blocks, so this holds by construction on every
+        generated-adapter path)."""
         if self.closed:
             raise ValueError("write to closed data pipe")
         if not self.accepts_blocks():
@@ -1041,62 +1007,48 @@ class DataPipeInput(_PhaseSpans):
     def __init__(
         self,
         filename: str,
+        config: Optional[PipeConfig] = None,
         directory: Optional[DirectoryLike] = None,
-        link: Optional[LinkSim] = None,
-        host: str = "127.0.0.1",
         channel: Optional[Channel] = None,
+        host: str = "127.0.0.1",
         import_workers: Optional[int] = None,
-        transport: Optional[str] = None,
-        shm_capacity: int = DEFAULT_RING_CAPACITY,
-        shm_doorbell: bool = True,
-        broadcast: int = 0,
-        arena: Optional[DecodeArena] = None,
-        streams: int = 1,
-        fanin: int = 1,
-        stream_window: int = DEFAULT_STREAM_WINDOW,
-        resume: Optional[str] = None,
-        attempt: int = 0,
-        lease_s: float = 0.0,
-        connect_timeout: float = 30.0,
-        trace: bool = False,
-        trace_ctx: str = "",
-        flight_depth: int = 64,
-        recorder: Optional[FlightRecorder] = None,
     ):
         rn = parse_reserved(filename)
         if rn is None:
             raise ValueError(f"{filename!r} is not a reserved pipe name")
         self.reserved = rn
-        self._attempt = attempt
-        if trace and not telemetry.tracing_enabled():
+        cfg = config or PipeConfig()
+        if channel is not None:  # a passed channel means that transport
+            cfg = replace(cfg, transport="channel")
+        self.config = cfg
+        if cfg.trace and not telemetry.tracing_enabled():
             telemetry.enable_tracing()
-        self._trace_on = trace or telemetry.tracing_enabled()
-        self._trace_ctx = trace_ctx or telemetry.current_ctx()
+        self._trace_on = cfg.trace or telemetry.tracing_enabled()
+        self._trace_ctx = cfg.trace_ctx or telemetry.current_ctx()
         self._t_open = time.monotonic()
         # phase spans parent to the whole-pipe span, recorded at close
         # under this id; the trace id is known once the schema hello has
         # been read (``_resolve_trace``)
         self._pipe_sid = telemetry.new_span_id() if self._trace_on else ""
         self._trace_id: Optional[str] = None
-        self._recorder = recorder or FlightRecorder(
-            flight_depth, name=f"import {rn.dataset}")
+        self._recorder = cfg.recorder or FlightRecorder(
+            name=f"import {rn.dataset}")
         self._recorder.note("import.open", dataset=rn.dataset,
-                            query=rn.query_id, transport=transport,
-                            attempt=attempt)
+                            query=rn.query_id, transport=cfg.transport,
+                            attempt=cfg.attempt)
         # registration context: what we publish in the directory so an
         # exporter with no context of its own joins *our* trace
         self._reg_ctx = ""
         if self._trace_on:
             self._reg_ctx = self._trace_ctx or telemetry.new_trace_ctx()
         directory = directory or get_directory()
-        self._connect_timeout = float(connect_timeout) or 30.0
-        if transport is None:
-            transport = "channel" if channel is not None else "socket"
-        if transport not in ("socket", "channel", "shm"):
+        self._connect_timeout = float(cfg.connect_timeout) or 30.0
+        if cfg.transport not in ("socket", "channel", "shm"):
             raise ValueError(
-                f"unknown transport {transport!r}; have socket/channel/shm")
+                f"unknown transport {cfg.transport!r}; have socket/channel/shm")
         workers = import_workers or rn.workers
-        if broadcast > 1 and (transport != "shm" or fanin > 1 or streams > 1):
+        if cfg.broadcast > 1 and (cfg.transport != "shm" or cfg.fanin > 1
+                                  or cfg.streams > 1):
             raise ValueError(
                 "broadcast pipes require transport='shm' with streams=1 "
                 "and fanin=1 (one ring, one writer, N reader cursors)")
@@ -1108,39 +1060,36 @@ class DataPipeInput(_PhaseSpans):
         self._replay_idx = 0
         self._resume_base = 0
         self._resume_skip = 0
-        if (resume is not None and fanin == 1 and streams == 1
-                and broadcast <= 1):
-            self._ledger = _resume_ledger(resume)
+        if (cfg.resume is not None and cfg.fanin == 1 and cfg.streams == 1
+                and cfg.broadcast <= 1):
+            self._ledger = _resume_ledger(cfg.resume)
             self._resume_base = len(self._ledger.staged)
-        _reg_kw: dict = {"lease_s": lease_s} if lease_s else {}
+        _reg_kw: dict = {"lease_s": cfg.lease_s} if cfg.lease_s else {}
         _res_kw: dict = (
-            {"resume_seq": self._resume_base, "resume_epoch": attempt}
+            {"resume_seq": self._resume_base, "resume_epoch": cfg.attempt}
             if self._ledger is not None else {})
         if self._reg_ctx:
             _res_kw["trace"] = self._reg_ctx
+        link = cfg.link
         _t_rdv = time.monotonic()
-        if fanin > 1:
+        if cfg.fanin > 1:
             self._transport: Transport = self._rendezvous_fanin(
-                rn, directory, transport, fanin, host, link, workers,
-                streams=streams, window=stream_window,
-                shm_capacity=shm_capacity, shm_doorbell=shm_doorbell)
-        elif streams > 1:
+                rn, directory, cfg, host, workers)
+        elif cfg.streams > 1:
             self._transport = self._rendezvous_striped(
-                rn, directory, transport, streams, stream_window,
-                host, link, shm_capacity, workers, shm_doorbell)
-        elif transport == "channel":
+                rn, directory, cfg, host, workers)
+        elif cfg.transport == "channel":
             ch = channel if channel is not None else Channel()
             directory.register(
                 rn.dataset, Endpoint(channel=ch, **_res_kw), rn.query_id,
                 import_workers=workers, **_reg_kw,
             )
             self._transport = ChannelTransport(ch, link)
-        elif transport == "shm" and broadcast > 1:
+        elif cfg.transport == "shm" and cfg.broadcast > 1:
             self._transport = self._rendezvous_broadcast(
-                rn, directory, broadcast, shm_capacity, shm_doorbell,
-                link, workers)
-        elif transport == "shm":
-            ring = acquire_ring(shm_capacity, doorbell=shm_doorbell)
+                rn, directory, cfg, workers)
+        elif cfg.transport == "shm":
+            ring = acquire_ring(cfg.shm_capacity)
             directory.register(
                 rn.dataset,
                 Endpoint(shm_name=ring.name, shm_capacity=ring.capacity,
@@ -1156,10 +1105,7 @@ class DataPipeInput(_PhaseSpans):
                 rn.dataset, Endpoint(h, p, **_res_kw), rn.query_id,
                 import_workers=workers, **_reg_kw,
             )
-            lsock.settimeout(60.0)
-            conn, _ = lsock.accept()
-            lsock.close()
-            self._transport = SocketTransport(conn, link)
+            self._transport = _accept_members([lsock], link)[0]
         self._t_rdv = (_t_rdv, time.monotonic())
         self._recorder.note("import.connected")
         if getattr(directory, "degraded", False):
@@ -1183,7 +1129,7 @@ class DataPipeInput(_PhaseSpans):
             f"before the exporter arrived — re-register (retried attempts "
             f"do this automatically)")
         renew = getattr(directory, "renew", None)
-        if lease_s and renew is not None:
+        if cfg.lease_s and renew is not None:
 
             def _on_lost(rn=rn):
                 # renew's documented 0: the lease expired and the
@@ -1202,8 +1148,8 @@ class DataPipeInput(_PhaseSpans):
             self._renewer = LeaseRenewer(
                 lambda ls, fn=renew, rn=rn: fn(rn.dataset, rn.query_id,
                                                lease_s=ls),
-                lease_s, on_lost=_on_lost).start()
-        self._arena = arena or DecodeArena()
+                cfg.lease_s, on_lost=_on_lost).start()
+        self._arena = DecodeArena()
         self.stats = PipeStats()
         self.schema: Optional[Schema] = None
         self.meta: dict = {}
@@ -1223,20 +1169,19 @@ class DataPipeInput(_PhaseSpans):
 
     # -- fabric rendezvous -------------------------------------------------------
     @staticmethod
-    def _rendezvous_broadcast(rn, directory, readers, shm_capacity,
-                              shm_doorbell, link, workers) -> Transport:
-        """Join the transfer's broadcast ring as one of ``readers``
-        cursors.  The directory hands out slot indexes: slot 0 creates
-        the ring (it owns the segment, like every shm importer) and
-        publishes its endpoint — which also registers it for the single
-        exporter's ``query`` — and slots 1..R-1 attach to it."""
+    def _rendezvous_broadcast(rn, directory, cfg: PipeConfig,
+                              workers) -> Transport:
+        """Join the transfer's broadcast ring as one of
+        ``cfg.broadcast`` reader cursors.  The directory hands out slot
+        indexes: slot 0 creates the ring (it owns the segment, like every
+        shm importer) and publishes its endpoint — which also registers it
+        for the single exporter's ``query`` — and slots 1..R-1 attach to
+        it."""
+        readers = cfg.broadcast
         slot, ep = directory.join_broadcast(
             rn.dataset, rn.query_id, readers=readers)
         if ep is None:  # first joiner: create (or re-lease warm) + publish
-            from .shm_ring import acquire_broadcast_ring
-
-            ring = acquire_broadcast_ring(shm_capacity, readers,
-                                          doorbell=shm_doorbell)
+            ring = acquire_broadcast_ring(cfg.shm_capacity, readers)
             directory.publish_broadcast(
                 rn.dataset,
                 Endpoint(shm_name=ring.name, shm_capacity=ring.capacity,
@@ -1246,55 +1191,24 @@ class DataPipeInput(_PhaseSpans):
             )
         else:
             ring = ShmRing.attach(ep.shm_name, role="reader", slot=slot)
-        return ShmRingTransport(ring, link)
+        return ShmRingTransport(ring, cfg.link)
 
     @staticmethod
-    def _rendezvous_striped(rn, directory, transport, streams, window,
-                            host, link, shm_capacity, workers,
-                            shm_doorbell: bool = True) -> Transport:
-        """Register one multi-endpoint group and reassemble N member
-        connections into one ordered stream (repro.core.stream)."""
-        if transport == "channel":
-            chans = [Channel() for _ in range(streams)]
-            members = tuple(Endpoint(channel=c) for c in chans)
-            directory.register(rn.dataset, Endpoint(members=members),
-                               rn.query_id, import_workers=workers)
-            parts: List[Transport] = [ChannelTransport(c, link) for c in chans]
-        elif transport == "shm":
-            rings = [acquire_ring(shm_capacity, doorbell=shm_doorbell)
-                     for _ in range(streams)]
-            members = tuple(
-                Endpoint(shm_name=r.name, shm_capacity=r.capacity)
-                for r in rings)
-            directory.register(rn.dataset, Endpoint(members=members),
-                               rn.query_id, import_workers=workers)
-            parts = [ShmRingTransport(r, link) for r in rings]
-        else:
-            lsocks = [listen_socket(host) for _ in range(streams)]
-            members = tuple(
-                Endpoint(*ls.getsockname()) for ls in lsocks)
-            directory.register(rn.dataset, Endpoint(members=members),
-                               rn.query_id, import_workers=workers)
-            parts = []
-            # the exporter (or the stub path) connects to the members in
-            # registration order, so sequential accepts pair up correctly;
-            # the listen backlog absorbs any out-of-order connects
-            for ls in lsocks:
-                ls.settimeout(60.0)
-                conn, _ = ls.accept()
-                ls.close()
-                parts.append(SocketTransport(conn, link))
-        return StripedReceiver(parts, window=window)
+    def _rendezvous_striped(rn, directory, cfg: PipeConfig, host,
+                            workers) -> Transport:
+        """Register one multi-endpoint group and reassemble
+        ``cfg.streams`` member connections into one ordered stream
+        (repro.core.stream)."""
+        members, parts, lsocks = _open_members(cfg, host)
+        directory.register(rn.dataset, Endpoint(members=members),
+                           rn.query_id, import_workers=workers)
+        return StripedReceiver(parts + _accept_members(lsocks, cfg.link))
 
     @staticmethod
-    def _rendezvous_fanin(rn, directory, transport, fanin, host, link,
-                          workers, streams: int = 1,
-                          window: int = DEFAULT_STREAM_WINDOW,
-                          shm_capacity: int = DEFAULT_RING_CAPACITY,
-                          shm_doorbell: bool = True,
-                          ) -> Transport:
+    def _rendezvous_fanin(rn, directory, cfg: PipeConfig, host,
+                          workers) -> Transport:
         """Register the shuffle's import-side rendezvous and merge
-        ``fanin`` exporter streams.
+        ``cfg.fanin`` exporter streams.
 
         Two wirings:
 
@@ -1312,6 +1226,8 @@ class DataPipeInput(_PhaseSpans):
           through :class:`FaninTransport` — this is how ``streams`` and
           ``partition`` compose on one pipe.
         """
+        transport, fanin, streams = cfg.transport, cfg.fanin, cfg.streams
+        link = cfg.link
         if streams <= 1 and transport != "shm":
             if transport == "channel":
                 ch = Channel(maxsize=64 * max(1, fanin))
@@ -1342,45 +1258,19 @@ class DataPipeInput(_PhaseSpans):
         # slotted wiring: everything is registered before anything blocks,
         # so the exporters' query_all returns only once every importer
         # published its full slot table
-        slot_eps: List[Endpoint] = []
-        slot_parts: List[List[Transport]] = []
-        slot_socks: List[List[socket.socket]] = []
-        for _ in range(fanin):
-            if transport == "channel":
-                chans = [Channel() for _ in range(streams)]
-                mems = tuple(Endpoint(channel=c) for c in chans)
-                slot_parts.append([ChannelTransport(c, link) for c in chans])
-                slot_socks.append([])
-            elif transport == "shm":
-                rings = [acquire_ring(shm_capacity, doorbell=shm_doorbell)
-                         for _ in range(streams)]
-                mems = tuple(
-                    Endpoint(shm_name=r.name, shm_capacity=r.capacity)
-                    for r in rings)
-                slot_parts.append([ShmRingTransport(r, link) for r in rings])
-                slot_socks.append([])
-            else:
-                lsocks = [listen_socket(host) for _ in range(streams)]
-                mems = tuple(Endpoint(*ls.getsockname()) for ls in lsocks)
-                slot_parts.append([])
-                slot_socks.append(lsocks)
-            slot_eps.append(mems[0] if streams == 1
-                            else Endpoint(members=mems))
+        slots = [_open_members(cfg, host) for _ in range(fanin)]
         directory.register(
-            rn.dataset, Endpoint(members=tuple(slot_eps), shared=True),
+            rn.dataset,
+            Endpoint(members=tuple(
+                mems[0] if streams == 1 else Endpoint(members=mems)
+                for mems, _, _ in slots), shared=True),
             rn.query_id, import_workers=workers,
         )
-        for parts, lsocks in zip(slot_parts, slot_socks):
-            for ls in lsocks:
-                ls.settimeout(60.0)
-                conn, _ = ls.accept()
-                ls.close()
-                parts.append(SocketTransport(conn, link))
-        slot_tr: List[Transport] = [
-            StripedReceiver(parts, window=window) if streams > 1
-            else parts[0]
-            for parts in slot_parts
-        ]
+        slot_tr: List[Transport] = []
+        for _, parts, lsocks in slots:
+            parts = parts + _accept_members(lsocks, link)
+            slot_tr.append(StripedReceiver(parts) if streams > 1
+                           else parts[0])
         return FaninTransport(slot_tr, expected_sources=fanin)
 
     # -- negotiation -------------------------------------------------------------
@@ -1792,7 +1682,7 @@ class DataPipeInput(_PhaseSpans):
         if per_stream is not None:
             self.stats.per_stream = per_stream()
         _record_stats(self.reserved, "import", self.stats,
-                      attempt=self._attempt)
+                      attempt=self.config.attempt)
         self._recorder.note("import.close",
                             replayed=self.stats.resume_replayed,
                             rows=self.stats.rows)
@@ -1824,7 +1714,7 @@ class DataPipeInput(_PhaseSpans):
             trace_id=self._trace_id, parent_id=self._trace_parent,
             span_id=self._pipe_sid,
             attrs={"dataset": rn.dataset, "query": rn.query_id,
-                   "attempt": self._attempt,
+                   "attempt": self.config.attempt,
                    "mode": self.meta.get("mode"),
                    "rows": self.stats.rows,
                    "replayed": self.stats.resume_replayed})
@@ -1899,6 +1789,41 @@ def _cheap_len(s: Any) -> int:
     return len(s) if isinstance(s, str) else 1
 
 
+def _open_members(cfg: PipeConfig, host: str
+                  ) -> Tuple[Tuple[Endpoint, ...], List[Transport],
+                             List[socket.socket]]:
+    """An importer's ``cfg.streams`` member endpoints of the configured
+    flavor, with their transports (channel, shm) or, for sockets, the
+    listening sockets to accept on once the endpoints are registered."""
+    n, link = cfg.streams, cfg.link
+    if cfg.transport == "channel":
+        chans = [Channel() for _ in range(n)]
+        return (tuple(Endpoint(channel=c) for c in chans),
+                [ChannelTransport(c, link) for c in chans], [])
+    if cfg.transport == "shm":
+        rings = [acquire_ring(cfg.shm_capacity) for _ in range(n)]
+        return (tuple(Endpoint(shm_name=r.name, shm_capacity=r.capacity)
+                      for r in rings),
+                [ShmRingTransport(r, link) for r in rings], [])
+    lsocks = [listen_socket(host) for _ in range(n)]
+    return tuple(Endpoint(*ls.getsockname()) for ls in lsocks), [], lsocks
+
+
+def _accept_members(lsocks: List[socket.socket],
+                    link: Optional[LinkSim]) -> List[Transport]:
+    """Accept one connection on each listening socket, in order.  The
+    exporter (or the stub path) connects to the members in registration
+    order, so sequential accepts pair up correctly; the listen backlog
+    absorbs any out-of-order connects."""
+    parts: List[Transport] = []
+    for ls in lsocks:
+        ls.settimeout(60.0)
+        conn, _ = ls.accept()
+        ls.close()
+        parts.append(SocketTransport(conn, link))
+    return parts
+
+
 def _connect(ep: Endpoint, link: Optional[LinkSim]) -> Transport:
     if ep.is_channel:
         # a shared channel (shuffle fan-in) is torn down by EOF counting,
@@ -1921,5 +1846,5 @@ def open_pipe_writer(filename: str, config: Optional[PipeConfig] = None, **kw) -
     return DataPipeOutput(filename, config=config, **kw)
 
 
-def open_pipe_reader(filename: str, **kw) -> DataPipeInput:
-    return DataPipeInput(filename, **kw)
+def open_pipe_reader(filename: str, config: Optional[PipeConfig] = None, **kw) -> DataPipeInput:
+    return DataPipeInput(filename, config=config, **kw)

@@ -216,22 +216,6 @@ def pipegen_open(
                 cfg = _replace(cfg, mode="bytes")
                 return _PipeBytesWriter(DataPipeOutput(str(filename), config=cfg))
             return _PipeTextWriter(DataPipeOutput(str(filename), config=cfg))
-        pipe = DataPipeInput(str(filename), link=cfg.link,
-                             transport=cfg.transport,
-                             shm_capacity=cfg.shm_capacity,
-                             shm_doorbell=cfg.shm_doorbell,
-                             broadcast=cfg.broadcast,
-                             arena=cfg.decode_arena,
-                             streams=cfg.streams,
-                             fanin=cfg.fanin,
-                             stream_window=cfg.stream_window,
-                             resume=cfg.resume,
-                             attempt=cfg.attempt,
-                             lease_s=cfg.lease_s,
-                             connect_timeout=cfg.connect_timeout,
-                             trace=cfg.trace,
-                             trace_ctx=cfg.trace_ctx,
-                             flight_depth=cfg.flight_depth,
-                             recorder=cfg.recorder)
+        pipe = DataPipeInput(str(filename), config=cfg)
         return _PipeBytesReader(pipe) if binary else pipe
     return (real_open or builtins.open)(filename, mode, **kw)

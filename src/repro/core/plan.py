@@ -161,7 +161,7 @@ class _Edge:
 #: options a subscribe() edge accepts (continuous pipes)
 _SUB_KEYS = frozenset(
     ("name", "transport", "mode", "codec", "retain_epochs", "retain_bytes",
-     "lease_s", "tenant", "qos", "shm_capacity", "doorbell", "streams",
+     "lease_s", "tenant", "qos", "shm_capacity", "streams",
      "timeout", "broadcast", "schema", "watermark"))
 
 
@@ -489,7 +489,7 @@ class TransferPlan:
             dst = ep.dst_engine
             key = (id(ep.src_engine), ep.table, ep.mode, ep.codec,
                    cfg.block_rows, cfg.text_format, cfg.delimiter,
-                   cfg.verify_first_n, cfg.shm_capacity, cfg.shm_doorbell,
+                   cfg.verify_first_n, cfg.shm_capacity,
                    id(cfg.link), ep.depends_on,
                    bool(getattr(dst, "writes_header", False)),
                    getattr(dst, "csv_delimiter", ","))
@@ -909,7 +909,7 @@ class CompiledPlan:
                     }
                     if bc and se in shm_edges:
                         kw["broadcast"] = bc
-                    for opt in ("shm_capacity", "doorbell", "lease_s"):
+                    for opt in ("shm_capacity", "lease_s"):
                         if opt in eo:
                             kw[opt] = eo[opt]
                     label = f"{name}->{se.dst.name}:{se.dst_table}"

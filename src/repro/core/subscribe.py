@@ -725,7 +725,7 @@ class Subscription:
                  transport: str = "shm", broadcast: int = 0,
                  group: str = "bc0", streams: int = 1,
                  shm_capacity: int = DEFAULT_RING_CAPACITY,
-                 doorbell: bool = True, lease_s: Optional[float] = None,
+                 lease_s: Optional[float] = None,
                  timeout: float = 30.0, link: Optional[LinkSim] = None,
                  host: str = "127.0.0.1",
                  apply: Optional[Callable[[EpochDelta], None]] = None,
@@ -757,7 +757,7 @@ class Subscription:
         self._renewer: Optional[LeaseRenewer] = None
 
         self._transport = self._rendezvous(
-            transport, broadcast, group, streams, shm_capacity, doorbell,
+            transport, broadcast, group, streams, shm_capacity,
             lease_s, timeout, host)
 
         self._recv_thread = threading.Thread(
@@ -767,7 +767,7 @@ class Subscription:
 
     # -- rendezvous -------------------------------------------------------------
     def _rendezvous(self, transport: str, broadcast: int, group: str,
-                    streams: int, shm_capacity: int, doorbell: bool,
+                    streams: int, shm_capacity: int,
                     lease_s: Optional[float], timeout: float,
                     host: str) -> Transport:
         d = self._directory
@@ -782,8 +782,7 @@ class Subscription:
                 self._dataset, _SUB_QUERY, readers=broadcast,
                 timeout=timeout)
             if ep is None:
-                ring = acquire_broadcast_ring(
-                    shm_capacity, broadcast, doorbell=doorbell)
+                ring = acquire_broadcast_ring(shm_capacity, broadcast)
                 d.publish_broadcast(
                     self._dataset,
                     Endpoint(shm_name=ring.name, shm_capacity=ring.capacity,
@@ -819,7 +818,7 @@ class Subscription:
             self._start_renewer(lease_s)
             return ChannelTransport(ch, self._link)
         if transport == "shm":
-            ring = acquire_ring(shm_capacity, doorbell=doorbell)
+            ring = acquire_ring(shm_capacity)
             d.register(self._dataset,
                        Endpoint(shm_name=ring.name,
                                 shm_capacity=ring.capacity,

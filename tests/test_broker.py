@@ -243,8 +243,8 @@ class _LeaseKiller(WorkerDirectory):
 def test_renew_zero_surfaces_as_loud_importer_failure():
     d = _LeaseKiller()
     set_directory(d)
-    inp = DataPipeInput("db://dead?workers=1&query=q0", transport="shm",
-                        lease_s=0.15)
+    inp = DataPipeInput("db://dead?workers=1&query=q0",
+                        config=PipeConfig(transport="shm", lease_s=0.15))
     try:
         with pytest.raises(BrokenPipeError) as e:
             inp.read(1)  # parked on the ring until the renew loop aborts it

@@ -145,11 +145,8 @@ def test_link_sim_latency_accounting():
 def test_pipe_stats_pool_hits_and_send_overlap():
     """The zero-copy/pipelined hot path must report its own win: pooled
     buffer reuse, copies avoided, and sender-thread overlap all nonzero."""
-    from repro.core.iobuf import BufferPool
-
     block = make_paper_block(600, seed=9, strings=True)
-    pool = BufferPool()
-    cfg = PipeConfig(mode="arrowcol", block_rows=64, pipelined=True, pool=pool)
+    cfg = PipeConfig(mode="arrowcol", block_rows=64)
     name = "db://stats?query=1"
     got = {}
 

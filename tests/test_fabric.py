@@ -206,8 +206,8 @@ def test_striped_pipe_roundtrip(transport):
     got = {}
 
     def imp():
-        pipe = DataPipeInput(name, transport=transport, streams=4,
-                             shm_capacity=1 << 22)
+        pipe = DataPipeInput(name, config=PipeConfig(
+            transport=transport, streams=4, shm_capacity=1 << 22))
         got["blocks"] = list(pipe.blocks())
         pipe.close()
         got["stats"] = pipe.stats
@@ -236,7 +236,8 @@ def test_striped_pipe_text_mode_roundtrip():
     got = {}
 
     def imp():
-        pipe = DataPipeInput(name, transport="channel", streams=2)
+        pipe = DataPipeInput(
+            name, config=PipeConfig(transport="channel", streams=2))
         got["text"] = pipe.read()
         pipe.close()
 
@@ -276,8 +277,9 @@ def test_striped_stub_eof_for_orphaned_importer():
     results = {}
 
     def imp(i):
-        pipe = DataPipeInput(name, streams=2, import_workers=2,
-                             transport="channel")
+        pipe = DataPipeInput(
+            name, config=PipeConfig(streams=2, transport="channel"),
+            import_workers=2)
         results[i] = sum(len(b) for b in pipe.blocks())
         pipe.close()
 
@@ -326,7 +328,8 @@ def test_shuffle_partitions_disjoint_and_consistent():
     parts = {}
 
     def imp(i):
-        pipe = DataPipeInput(name_imp, fanin=1, import_workers=3)
+        pipe = DataPipeInput(name_imp, config=PipeConfig(fanin=1),
+                             import_workers=3)
         blocks = list(pipe.blocks())
         pipe.close()
         parts[i] = (ColumnBlock.concat(blocks) if blocks

@@ -297,7 +297,7 @@ def test_importer_lease_renewal_keeps_slow_rendezvous_alive():
     d = WorkerDirectory(lease_ttl=0.2)
     set_directory(d)
     pipe = DataPipeInput("db://leased?workers=1&query=L1",
-                         transport="channel", lease_s=0.2)
+                         config=PipeConfig(transport="channel", lease_s=0.2))
     try:
         time.sleep(0.65)  # > 3 lease TTLs
         ep = d.query("leased", "L1", timeout=0.5)

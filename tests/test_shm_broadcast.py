@@ -96,8 +96,8 @@ def test_broadcast_1x3_bit_identical_in_process():
     got = {}
 
     def imp(i):
-        pipe = DataPipeInput(name, transport="shm", broadcast=3,
-                             shm_capacity=1 << 20)
+        pipe = DataPipeInput(name, config=PipeConfig(
+            transport="shm", broadcast=3, shm_capacity=1 << 20))
         got[i] = list(pipe.blocks())
         pipe.close()
         got[f"stats{i}"] = pipe.stats
@@ -177,8 +177,8 @@ def test_broadcast_slow_reader_applies_backpressure():
 
 def _child_bcast_importer(dir_addr, name, q, idx):
     set_directory(DirectoryClient(*dir_addr))
-    pipe = DataPipeInput(name, transport="shm", broadcast=3,
-                         shm_capacity=1 << 20)
+    pipe = DataPipeInput(name, config=PipeConfig(
+        transport="shm", broadcast=3, shm_capacity=1 << 20))
     rows = 0
     key_sum = 0
     for block in pipe.blocks():

@@ -292,7 +292,8 @@ def test_poll_fallback_keeps_shm_transfers_green(monkeypatch):
     got = {}
 
     def imp():
-        pipe = DataPipeInput(name, transport="shm", shm_capacity=1 << 20)
+        pipe = DataPipeInput(name, config=PipeConfig(
+            transport="shm", shm_capacity=1 << 20))
         got["blocks"] = list(pipe.blocks())
         pipe.close()
         got["stats"] = pipe.stats
@@ -318,7 +319,8 @@ def test_poll_fallback_keeps_shm_transfers_green(monkeypatch):
 
 def _child_importer(dir_addr, name, q):
     set_directory(DirectoryClient(*dir_addr))
-    pipe = DataPipeInput(name, transport="shm", shm_capacity=1 << 20)
+    pipe = DataPipeInput(name, config=PipeConfig(
+        transport="shm", shm_capacity=1 << 20))
     ring_name = pipe._transport.ring.name
     rows = 0
     key_sum = 0
@@ -451,7 +453,8 @@ def test_in_process_shm_transfer_matches_channel_semantics():
     got = {}
 
     def imp():
-        pipe = DataPipeInput(name, transport="shm", shm_capacity=1 << 20)
+        pipe = DataPipeInput(name, config=PipeConfig(
+            transport="shm", shm_capacity=1 << 20))
         got["blocks"] = list(pipe.blocks())
         pipe.close()
         got["stats"] = pipe.stats

@@ -185,7 +185,7 @@ def test_traced_transfer_single_trace_in_process():
     name = "db://ttrace?query=1"
 
     def imp():
-        pipe = DataPipeInput(name, trace=True)
+        pipe = DataPipeInput(name, config=PipeConfig(trace=True))
         list(pipe.blocks())
         pipe.close()
 
@@ -296,7 +296,8 @@ def _child_import(host, port, name, transport):
     from repro.core.directory import DirectoryClient, set_directory
 
     set_directory(DirectoryClient(host, port))
-    pipe = DataPipeInput(name, transport=transport, trace=True)
+    pipe = DataPipeInput(
+        name, config=PipeConfig(transport=transport, trace=True))
     n = sum(len(b) for b in pipe.blocks())
     pipe.close()
     assert n == 96, n
@@ -479,7 +480,7 @@ def test_raised_pipe_error_carries_flight_timeline():
     d.renew = lambda *a, **k: 0  # every renewal finds the entry gone
     set_directory(d)
     pipe = DataPipeInput("db://flt?workers=1&query=f1",
-                         transport="channel", lease_s=0.2)
+                         config=PipeConfig(transport="channel", lease_s=0.2))
     try:
         assert pipe._lease_lost.wait(10)
         with pytest.raises(BrokenPipeError) as ei:

@@ -224,8 +224,8 @@ def test_pipelined_send_error_surfaces_on_close_and_reader_terminates():
     t.start()
     # block_rows > row count: the single block is flushed inside close(),
     # so close() is the first place the sender error can possibly surface
-    out = DataPipeOutput(name, config=PipeConfig(mode="arrowcol", block_rows=1024,
-                                                 pipelined=True))
+    out = DataPipeOutput(name, config=PipeConfig(mode="arrowcol",
+                                                 block_rows=1024))
 
     real_send = out._transport.send_frames
 
@@ -256,8 +256,8 @@ def test_pipelined_writer_fails_fast_after_sender_error():
 
     t = threading.Thread(target=imp, daemon=True)
     t.start()
-    out = DataPipeOutput(name, config=PipeConfig(mode="arrowcol", block_rows=8,
-                                                 pipelined=True))
+    out = DataPipeOutput(name, config=PipeConfig(mode="arrowcol",
+                                                 block_rows=8))
 
     def broken_send(kind, segs):
         raise _BoomError("wire fell over")
