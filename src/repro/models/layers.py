@@ -289,7 +289,36 @@ def attention_decode(params: Params, cfg: ModelConfig, x: jnp.ndarray,
     token in each row, so every row is its own sequence.
     Returns (out [B,1,d], new_cache_k, new_cache_v).
     """
-    B, S, KV, hd = cache_k.shape
+    q, k, v = _decode_qkv(params, cfg, x, index)
+    rows = jnp.arange(x.shape[0])
+    cache_k = cache_k.at[rows, index].set(k[:, 0].astype(cache_k.dtype))
+    cache_v = cache_v.at[rows, index].set(v[:, 0].astype(cache_v.dtype))
+    out = _attend_cache(params, cfg, q, cache_k, cache_v, index, x.dtype)
+    return out, cache_k, cache_v
+
+
+def attention_decode_stacked(params: Params, cfg: ModelConfig,
+                             x: jnp.ndarray, stack_k: jnp.ndarray,
+                             stack_v: jnp.ndarray, layer: jnp.ndarray,
+                             index: jnp.ndarray
+                             ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """``attention_decode`` for layer ``layer`` of caches stacked
+    [L, B, S, KV, hd]: the new key and value are written at [layer, row,
+    index[row]], one position per row, so a stack carried through the layer
+    scan is updated in place; attention reads that layer's slice.
+    Returns (out [B,1,d], new stack_k, new stack_v)."""
+    q, k, v = _decode_qkv(params, cfg, x, index)
+    rows = jnp.arange(x.shape[0])
+    stack_k = stack_k.at[layer, rows, index].set(k[:, 0].astype(stack_k.dtype))
+    stack_v = stack_v.at[layer, rows, index].set(v[:, 0].astype(stack_v.dtype))
+    out = _attend_cache(params, cfg, q, stack_k[layer], stack_v[layer],
+                        index, x.dtype)
+    return out, stack_k, stack_v
+
+
+def _decode_qkv(params: Params, cfg: ModelConfig, x: jnp.ndarray,
+                index: jnp.ndarray):
+    """The new token's q, k, v [B,1,heads,hd], rotated to its position."""
     q, k, v = _project_qkv(params, x, x)
     pos = index[:, None].astype(jnp.int32)               # [B, 1]
     if cfg.rope == "mrope":
@@ -297,16 +326,21 @@ def attention_decode(params: Params, cfg: ModelConfig, x: jnp.ndarray,
         q, k = apply_mrope(q, pos3, cfg.rope_theta), apply_mrope(k, pos3, cfg.rope_theta)
     elif cfg.rope == "rope":
         q, k = apply_rope(q, pos, cfg.rope_theta), apply_rope(k, pos, cfg.rope_theta)
-    rows = jnp.arange(B)
-    cache_k = cache_k.at[rows, index].set(k[:, 0].astype(cache_k.dtype))
-    cache_v = cache_v.at[rows, index].set(v[:, 0].astype(cache_v.dtype))
+    return q, k, v
+
+
+def _attend_cache(params: Params, cfg: ModelConfig, q: jnp.ndarray,
+                  cache_k: jnp.ndarray, cache_v: jnp.ndarray,
+                  index: jnp.ndarray, dtype) -> jnp.ndarray:
+    """q [B,1,H,hd] over each row's cache [B,S,KV,hd] up to and including
+    index[row]; returns the output projection [B,1,d] in ``dtype``."""
+    S = cache_k.shape[1]
     scores = _gqa_scores(q, cache_k, cfg.attn_scale)     # [B,H,1,S]
     valid = jnp.arange(S)[None, :] <= index[:, None]     # [B,S]
     scores = jnp.where(valid[:, None, None, :], scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1)
     o = _gqa_out(w, cache_v)
-    out = jnp.einsum("bshk,hkd->bsd", o.astype(x.dtype), params["wo"])
-    return out, cache_k, cache_v
+    return jnp.einsum("bshk,hkd->bsd", o.astype(dtype), params["wo"])
 
 
 def cross_attention_decode(params: Params, cfg: ModelConfig, x: jnp.ndarray,

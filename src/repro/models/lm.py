@@ -25,6 +25,7 @@ from .layers import (
     Params,
     attention,
     attention_decode,
+    attention_decode_stacked,
     attention_init,
     dtype_of,
     embed,
@@ -329,6 +330,15 @@ def init_cache(cfg: ModelConfig, batch: int, seq: int) -> Dict[str, Any]:
     }
 
 
+def decode_carries_cache(cfg: ModelConfig) -> bool:
+    """Whether ``decode_step`` carries the whole cache through its layer
+    scan and rewrites it in place, as a layer list and the dense, moe and
+    vlm scan do, so that a caller can donate it.  rwkv6 and the zamba-style
+    hybrid read their state as scan inputs and write it as outputs, which
+    cannot alias: donated, the compiler would copy it."""
+    return bool(cfg.layer_types) or cfg.family in ("dense", "moe", "vlm")
+
+
 def decode_step(params: Params, cfg: ModelConfig, cache: Dict[str, Any],
                 batch: Dict[str, jnp.ndarray], mesh=None):
     """One new token against the cache.  batch: {"token": [B,1]} (vlm:
@@ -400,21 +410,25 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Dict[str, Any],
         new_cache = dict(cache, conv=conv, ssm=ssm, shared_k=sk, shared_v=sv,
                          index=index + 1)
     else:
+        # the stacked keys and values ride in the carry, and each layer
+        # writes one position per row into them in place
         def body(carry, inp):
-            h = carry
-            lp, k_l, v_l = inp
+            h, k, v = carry
+            lp, layer = inp
             a = rmsnorm(lp["ln_attn"], h, cfg.norm_eps)
-            o, k_l, v_l = attention_decode(lp["attn"], cfg, a, k_l, v_l, index)
+            o, k, v = attention_decode_stacked(lp["attn"], cfg, a, k, v,
+                                               layer, index)
             h = h + o
             a = rmsnorm(lp["ln_mlp"], h, cfg.norm_eps)
             if cfg.moe_experts:
                 h = h + moe(lp["moe"], cfg, a, mesh)
             else:
                 h = h + mlp(lp["mlp"], a)
-            return h, (k_l, v_l)
+            return (h, k, v), None
 
-        x, (k, v) = jax.lax.scan(body, x, (params["layers"], cache["k"],
-                                           cache["v"]))
+        (x, k, v), _ = jax.lax.scan(
+            body, (x, cache["k"], cache["v"]),
+            (params["layers"], jnp.arange(cfg.n_layers)))
         new_cache = dict(cache, k=k, v=v, index=index + 1)
 
     x = rmsnorm(params["ln_final"], x, cfg.norm_eps)

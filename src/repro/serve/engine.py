@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import telemetry
-from ..models import Model
+from ..models import Model, lm
 
 __all__ = ["FeatureView", "ServeEngine", "GenerationResult"]
 
@@ -116,14 +116,14 @@ def _shared_decode_step(model: Model, mesh):
                 scores = logits.astype(jnp.float32) / temperature + noise
                 return jnp.argmax(scores, axis=-1).astype(jnp.int32), cache, rng
 
-            # A layer-list step carries its state through the layer scan
-            # and rewrites it in place, so its cache is donated: undonated,
-            # each step allocated a second whole cache, and near a full chip
-            # the allocator stalled steps for 0.1-1 s.  The dense scan reads
-            # the cache as scan inputs and writes it as outputs; donated,
-            # the compiler copies it whole twice more and the step is slower.
-            fn = jax.jit(step,
-                         donate_argnums=(1,) if model.cfg.layer_types else ())
+            # A step that carries its cache through the layer scan and
+            # rewrites it in place (a layer list, and the dense, moe and vlm
+            # scan) has it donated: undonated, each step allocates a second
+            # whole cache and copies the old one into it.  rwkv6 and the
+            # zamba-style hybrid read their state as scan inputs and write
+            # it as outputs, which cannot alias, so theirs stays undonated.
+            carried = lm.decode_carries_cache(model.cfg)
+            fn = jax.jit(step, donate_argnums=(1,) if carried else ())
             per_model[key] = fn
         return fn
 

@@ -1,9 +1,9 @@
 """Compile for a described TPU v5e, with no chip attached: the Pallas
 kernels at the widths of the registered configs that would call them, and
 the qwen2-1.5b decode step and the serving engine's step around it at full
-width (depth cut to 2 layers), and the granite-4.0-h-micro decode step
-whole.  What the chip's compiler refuses, or cannot fit, fails here, at no
-chip time.
+width (depth cut to 2 layers, and whole for the cache written in place),
+and the granite-4.0-h-micro decode step whole.  What the chip's compiler
+refuses, or cannot fit, fails here, at no chip time.
 
 The topology is described only inside the fixture: only one process at a
 time may load the TPU library, so no module may do it while it is imported.
@@ -105,6 +105,29 @@ def test_qwen2_engine_step_hands_out_token_ids_for_v5e(v5e_chip):
     cache_bytes = sum(x.size * x.dtype.itemsize
                       for x in jax.tree_util.tree_leaves(cache))
     assert mem.output_size_in_bytes < cache_bytes + 1e6
+
+
+def test_qwen2_engine_step_rewrites_the_cache_in_place_for_v5e(v5e_chip):
+    """The serving engine's greedy step for qwen2-1.5b at full depth and the
+    serve cell's 64 rows x 2048: the donated cache is aliased to the step's
+    output, and no temporary holds a layer's slab or a second cache.  Each
+    layer writes one position per row into the stacked keys and values, so
+    no dynamic-update-slice rewrites the whole [28,64,2048,2,128] stack."""
+    model = build_model(get_config("qwen2-1.5b"))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(64, 2048))
+    token = jax.ShapeDtypeStruct((64, 1), jnp.int32)
+    compiled = _shared_decode_step(model, None).lower(
+        _on(v5e_chip, params), _on(v5e_chip, cache),
+        {"token": _on(v5e_chip, token)}).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 1e9
+    stack = "[28,64,2048,2,128]"
+    assert not [line for line in compiled.as_text().splitlines()
+                if "dynamic-update-slice(" in line and stack in line]
 
 
 def test_granite_decode_step_fits_one_v5e(v5e_chip):

@@ -102,6 +102,46 @@ def test_decode_matches_forward_dense():
                                rtol=2e-4, atol=2e-4)
 
 
+def test_dense_decode_writes_one_position_per_row():
+    """Rows at ragged positions decode through the donated, carried cache:
+    each step's logits equal the full forward's at that row's position, and
+    the step changes the cache only at [:, row, index[row]]."""
+    cfg = get_config("qwen2-1.5b").reduced()
+    model = build_model(cfg)
+    params = model.init(RNG)
+    starts, steps, S = [0, 2, 5], 6, 16
+    toks = jax.random.randint(jax.random.PRNGKey(5), (len(starts), 11), 0,
+                              cfg.vocab)
+    full = np.asarray(model.forward(params, {"tokens": toks}), np.float32)
+    step = jax.jit(lambda p, c, b: model.decode_step(p, c, b),
+                   donate_argnums=(1,))
+
+    def prefilled(row):
+        cache = model.init_cache(1, S)
+        for t in range(starts[row]):
+            _, cache = step(params, cache,
+                            {"token": toks[row:row + 1, t:t + 1]})
+        return cache
+
+    cache = jax.tree_util.tree_map(
+        lambda *rows: jnp.concatenate(rows, axis=0 if rows[0].ndim == 1 else 1),
+        *[prefilled(r) for r in range(len(starts))])
+    rows = np.arange(len(starts))
+    for t in range(steps):
+        at = np.asarray(starts) + t
+        np.testing.assert_array_equal(np.asarray(cache["index"]), at)
+        before = jax.tree_util.tree_map(np.asarray, cache)
+        logits, cache = step(params, cache,
+                             {"token": toks[rows, at][:, None]})
+        np.testing.assert_allclose(np.asarray(logits[:, 0], np.float32),
+                                   full[rows, at], rtol=2e-4, atol=2e-4)
+        for name in ("k", "v"):
+            new, old = np.array(cache[name]), before[name]
+            assert (new[:, rows, at] != 0).any(axis=(-2, -1)).all()
+            new[:, rows, at] = old[:, rows, at]
+            np.testing.assert_array_equal(new, old)
+
+
 def test_decode_matches_forward_rwkv():
     """Stateful decode equals the scan-over-time forward (rwkv6)."""
     cfg = get_config("rwkv6-3b").reduced()

@@ -128,11 +128,15 @@ def test_serve_engine_rows_are_independent():
 
 
 @pytest.mark.parametrize("name, donated", [("granite-4.0-h-micro", True),
-                                           ("qwen2-1.5b", False)])
+                                           ("qwen2-1.5b", True),
+                                           ("grok-1-314b", True),
+                                           ("rwkv6-3b", False)])
 def test_serve_engine_decode_step_donates_the_cache(name, donated):
-    """A layer-list step rewrites the cache in place: the cache it was
+    """A step that carries its cache through the layer scan (a layer list,
+    the dense scan and its moe body) rewrites it in place: the cache it was
     handed is consumed, so the engine never holds two whole caches at once.
-    The dense step keeps its cache undonated."""
+    rwkv6 reads its state as scan inputs and writes it as outputs, which
+    cannot alias, so its cache stays undonated."""
     model = build_model(get_config(name).reduced())
     eng = ServeEngine(model, model.init(jax.random.PRNGKey(0)),
                       batch_size=2, max_context=16, eos_token=-1)
